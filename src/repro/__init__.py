@@ -11,6 +11,4 @@ controller and frees the remaining controllers for scalar/control work that
 overlaps with device compute.
 """
 
-from repro import compat as _compat  # noqa: F401  (installs jax 0.4.x shims)
-
 __version__ = "1.0.0"

@@ -1,5 +1,7 @@
 from repro.common.utils import (
+    REPO_ROOT,
     Timer,
+    enable_compile_cache,
     human_bytes,
     human_flops,
     human_num,
@@ -9,7 +11,9 @@ from repro.common.utils import (
 )
 
 __all__ = [
+    "REPO_ROOT",
     "Timer",
+    "enable_compile_cache",
     "human_bytes",
     "human_flops",
     "human_num",

@@ -1,12 +1,36 @@
-"""Small shared utilities: timing, humanized units, pytree accounting."""
+"""Small shared utilities: timing, humanized units, pytree accounting, and
+the persistent compilation cache of the entry points."""
 
 from __future__ import annotations
 
+import os
 import time
+from pathlib import Path
 from typing import Any
 
 import jax
 import numpy as np
+
+# the checkout root (``src/repro/common/utils.py`` -> three levels up)
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    this sets nothing. Otherwise the cache lives at ``<repo>/.jax_cache``:
+    a fixed path, because the path is part of the cache key — a directory
+    that moves never hits. Entry points call this (``launch/serve.py``,
+    ``chip_smoke.py``); importing the package never does, so tests and
+    library users keep JAX's own setting."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class Timer:

@@ -1,7 +1,8 @@
-"""codeqwen1.5-7b — dense MHA (kv == heads) transformer, qwen1.5 arch.
+"""codeqwen1.5-7b — dense GQA transformer (32 query heads over 4 KV heads),
+qwen1.5 arch.
 
 [hf:Qwen/CodeQwen1.5-7B; hf]
-32L d_model=4096 32H (GQA kv=32) d_ff=13440 vocab=92416
+32L d_model=4096 32H (GQA kv=4) d_ff=13440 vocab=92416 — 7.25B params
 """
 
 from repro.configs.base import ArchConfig, register_arch
@@ -14,7 +15,7 @@ CONFIG = register_arch(
         n_layers=32,
         d_model=4096,
         n_heads=32,
-        n_kv_heads=32,
+        n_kv_heads=4,
         d_head=128,
         d_ff=13440,
         vocab_size=92416,

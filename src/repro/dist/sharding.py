@@ -270,30 +270,33 @@ def replicated(info: MeshInfo) -> NamedSharding:
 # =============================================================================
 
 
-# cache leaves whose dim 2 is the SEQUENCE axis ([L, B, S, ...]): the
-# attention K/V pools, the hybrid shared-block pools, the MLA latent/rope
-# caches (see LM.init_cache), and the quantized cache's per-row scale
-# leaves [L, B, S, KV] (their KV dim shards with the payload's KV heads;
-# a replicated fallback still broadcasts cleanly against a head_dim-sharded
-# payload). Everything else is recurrent state with no positional axis.
-_SEQ_CACHE_KEYS = frozenset(
-    {"k", "v", "k_scale", "v_scale", "attn_k", "attn_v", "ckv", "krope"}
+# positional cache leaves (see LM.init_cache): the head-major attention K/V
+# ``[L, B, KV, S, hd]`` (and the paged pool ``[L, NB, KV, bs, hd]``), the
+# hybrid shared-block K/V, and the quantized cache's per-row scales
+# ``[L, B, KV, S]`` — their KV dim shards with the payload's KV heads (a
+# replicated scale fallback still broadcasts cleanly against a
+# head_dim-sharded payload); and the MLA latent/rope caches
+# ``[L, B, S, rank]``. Everything else is recurrent state with no
+# positional axis.
+HEAD_MAJOR_CACHE_KEYS = frozenset(
+    {"k", "v", "k_scale", "v_scale", "attn_k", "attn_v"}
 )
+_LATENT_CACHE_KEYS = frozenset({"ckv", "krope"})
 
 
 def serve_cache_shardings(cache_shape: Any, info: MeshInfo) -> Any:
-    """KV-cache placement for the SERVING slot pool — ``[L, B_slots, S_max,
-    KV, hd]`` / MLA ``[L, B_slots, S_max, rank]`` leaves plus SSM state
-    ``[L, B_slots, ...]``.
+    """KV-cache placement for the SERVING slot pool — head-major
+    ``[L, B_slots, KV, S_max, hd]`` / MLA ``[L, B_slots, S_max, rank]``
+    leaves plus SSM state ``[L, B_slots, ...]``.
 
     Differs from training-time ``LM.cache_shardings`` on purpose: the
     serving engine scatters single rows at arbitrary ``(slot, pos)`` every
     tick, so the slot (B) and sequence (S) dims are NEVER sharded — a
     model-axis split of either would turn every O(1) cache write into a
-    cross-shard exchange. Positional caches (leaf names in
-    ``_SEQ_CACHE_KEYS``) partition only dims past the sequence axis: KV
-    heads first (clean head parallelism, matching ``spec_for_param``'s
-    attention rule), head_dim/latent-rank as the fallback. Recurrent SSM
+    cross-shard exchange. Head-major K/V leaves partition their KV heads
+    (dim 2: clean head parallelism, matching ``spec_for_param``'s attention
+    rule and the per-head ``shard_map`` the attention kernels run under),
+    head_dim as the fallback; MLA leaves their latent rank. Recurrent SSM
     leaves take their widest trailing dim ≥ dim 2. The layer stack dim 0 is
     never sharded.
     """
@@ -303,10 +306,11 @@ def serve_cache_shardings(cache_shape: Any, info: MeshInfo) -> Any:
         parts: list[Any] = [None] * leaf.ndim
         if ms > 1 and leaf.ndim >= 2:
             name = getattr(path[-1], "key", None) if path else None
-            if name in _SEQ_CACHE_KEYS:
-                # [L, B, S, ...]: only dims PAST the sequence axis are
-                # eligible — (kv_)heads first on 5-D, head_dim/rank last
-                order = [d for d in (leaf.ndim - 2, leaf.ndim - 1) if d >= 3]
+            if name in HEAD_MAJOR_CACHE_KEYS:
+                # [L, B, KV, S(, hd)]: heads first, head_dim on 5-D payloads
+                order = [2] + ([4] if leaf.ndim == 5 else [])
+            elif name in _LATENT_CACHE_KEYS:
+                order = [leaf.ndim - 1]  # [L, B, S, rank]: the rank
             else:
                 # SSM conv/recurrent state [L, B, ...]: widest trailing dim
                 order = sorted(

@@ -13,7 +13,7 @@ hardcoded ``block=128`` for every call. The tuner:
   re-sweeping.
 
 Cache location: ``$REPRO_AUTOTUNE_CACHE`` if set, else
-``~/.cache/repro/autotune.json``. Sweeping is opt-in via
+``<repo>/.autotune.json`` (inside the checkout, never the home directory). Sweeping is opt-in via
 ``REPRO_AUTOTUNE=1`` (a sweep in interpret mode on CPU is expensive);
 without it, a cache miss returns the per-op heuristic default and nothing
 is written. Entries are keyed on a schema version — bump
@@ -112,7 +112,9 @@ def default_cache_path() -> str:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro", "autotune.json")
+    from repro.common.utils import REPO_ROOT
+
+    return str(REPO_ROOT / ".autotune.json")
 
 
 class Autotuner:

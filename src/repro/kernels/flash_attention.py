@@ -30,15 +30,20 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _online_softmax_update(s, v, m_ref, l_ref, acc_ref):
-    """One K-tile's online (max, sum, acc) update. s: [bq, bk] f32 scores."""
+def _online_softmax_update(s, v, m_ref, l_ref, acc_ref, v_row_scale=None):
+    """One K-tile's online (max, sum, acc) update. s: [bq, bk] f32 scores.
+
+    ``v_row_scale`` ([1, bk] f32) dequantizes int8 value rows inside the
+    readout: ``p @ (v * vs[:, None]) == (p * vs) @ v``, so the per-position
+    scale multiplies the probability lanes instead of the [bk, d] tile."""
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    pv = p if v_row_scale is None else p * v_row_scale
     acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
+        pv, v, preferred_element_type=jnp.float32
     )
     m_ref[...] = m_new
 

@@ -308,14 +308,14 @@ def decode_attention(
 ):
     """Batched single-token decode attention against the KV cache.
 
-    q: [B, H, d] (the new token's query heads); k/v: [B, S_max, KV, d]
-    (decode-cache layout, possibly lower-precision storage); cur_len: []
-    or [B] tokens already cached per slot. ``k_scale``/``v_scale``
-    ([B, S_max, KV] f32, the cache-resident scale leaves) mark K/V as
+    q: [B, H, d] (the new token's query heads); k/v: [B, KV, S_max, d]
+    (head-major decode-cache layout, possibly lower-precision storage);
+    cur_len: [] or [B] tokens already cached per slot. ``k_scale``/``v_scale``
+    ([B, KV, S_max] f32, the cache-resident scale leaves) mark K/V as
     int8 rows — dequant happens inside the kernel / oracle, never as an
     f32 cache copy. Returns [B, H, d]."""
     b, h, d = q.shape
-    s_max, kvh = k.shape[1], k.shape[2]
+    kvh, s_max = k.shape[1], k.shape[2]
     assert h % kvh == 0, (h, kvh)
     g = h // kvh
     qg = q.reshape(b, kvh, g, d)
@@ -332,14 +332,12 @@ def decode_attention(
                 kv_dtype=None if k_scale is None else k.dtype,
             )["block_s"]
         # no pre-cast of the cache: the kernel upcasts per-tile (int8/bf16
-        # storage reads stay at storage width in HBM); scales gain a
-        # trailing singleton so they ride the payloads' BlockSpec maps
+        # storage reads stay at storage width in HBM) and reads the cache
+        # and its scales in place
         out = _decode_k.decode_attention(
             qg, k, v, cur_len,
             window=window, block_s=min(block_s, s_max),
-            interpret=(m == "interpret"),
-            k_scale=None if k_scale is None else k_scale[..., None],
-            v_scale=None if v_scale is None else v_scale[..., None],
+            interpret=(m == "interpret"), k_scale=k_scale, v_scale=v_scale,
         )
     return out.reshape(b, h, d)
 
@@ -353,15 +351,15 @@ def ragged_attention(
     batched cache. The unified serving dispatch routes every tick through
     this one op instead of choosing between prefill and decode programs.
 
-    q: [T, H, d] packed query tokens; k/v: [B, S_max, KV, d] (decode-cache
-    layout, possibly lower-precision storage) with the packed tokens' K/V
+    q: [T, H, d] packed query tokens; k/v: [B, KV, S_max, d] (head-major
+    decode-cache layout, possibly lower-precision storage) with the packed tokens' K/V
     already scattered at (tok_slot, tok_pos); tok_slot/tok_pos: [T] int32.
     ``valid`` optionally passes a precomputed ``ref.ragged_valid_mask``
     (descriptor-only, so one mask serves every layer of a packed step); the
     Pallas kernel derives its masks in-kernel and ignores it.
     Returns [T, H, d]."""
     t, h, d = q.shape
-    s_max, kvh = k.shape[1], k.shape[2]
+    kvh, s_max = k.shape[1], k.shape[2]
     assert h % kvh == 0, (h, kvh)
     g = h // kvh
     qg = q.reshape(t, kvh, g, d)
@@ -382,9 +380,7 @@ def ragged_attention(
         out = _ragged_k.ragged_attention(
             qg, k, v, tok_slot, tok_pos,
             window=window, block_s=min(block_s, s_max),
-            interpret=(m == "interpret"),
-            k_scale=None if k_scale is None else k_scale[..., None],
-            v_scale=None if v_scale is None else v_scale[..., None],
+            interpret=(m == "interpret"), k_scale=k_scale, v_scale=v_scale,
         )
     return out.reshape(t, h, d)
 
@@ -419,9 +415,9 @@ def mla_ragged_attention(
     gain = scale * d_tot**0.5  # kernel divides by sqrt(d_tot); we undo it
     q_cat = jnp.concatenate([q_eff * gain, q_rope * gain], axis=-1)
     qg = q_cat.reshape(t, 1, h, d_tot)  # ONE shared latent KV head
-    k_cat = jnp.concatenate([ckv, krope], axis=-1)[:, :, None, :]
-    v_pad = jnp.concatenate([ckv, jnp.zeros_like(krope)], axis=-1)[:, :, None, :]
-    s_max = k_cat.shape[1]
+    k_cat = jnp.concatenate([ckv, krope], axis=-1)[:, None]  # [B, 1, S, d]
+    v_pad = jnp.concatenate([ckv, jnp.zeros_like(krope)], axis=-1)[:, None]
+    s_max = k_cat.shape[2]
     if block_s is None:
         block_s = _blocks("ragged_attention", k_cat.shape, q_eff.dtype, m)[
             "block_s"
@@ -442,7 +438,7 @@ def paged_ragged_attention(
     ``(slot, pos)`` descriptor indirection of :func:`ragged_attention`
     generalized to ``(block, offset)`` through per-sequence block tables.
 
-    q: [T, H, d] packed query tokens; k/v: [num_blocks, block_size, KV, d]
+    q: [T, H, d] packed query tokens; k/v: [num_blocks, KV, block_size, d]
     pool with the packed tokens' K/V already scattered at their (block,
     offset); tok_seq/tok_pos: [T] int32 — token t belongs to block-table
     row ``tok_seq[t]`` at absolute position ``tok_pos[t]``; block_tables:
@@ -451,7 +447,7 @@ def paged_ragged_attention(
     the Pallas kernel streams pool blocks straight through its index map
     — no gathered view ever exists on TPU. Returns [T, H, d]."""
     t, h, d = q.shape
-    kvh = k.shape[2]
+    kvh = k.shape[1]
     assert h % kvh == 0, (h, kvh)
     qg = q.reshape(t, kvh, h // kvh, d)
     m = _resolve(mode)
@@ -465,8 +461,7 @@ def paged_ragged_attention(
         out = _ragged_k.paged_ragged_attention(
             qg, k, v, tok_seq, tok_pos, block_tables,
             window=window, interpret=(m == "interpret"),
-            k_scale=None if k_scale is None else k_scale[..., None],
-            v_scale=None if v_scale is None else v_scale[..., None],
+            k_scale=k_scale, v_scale=v_scale,
         )
     return out.reshape(t, h, d)
 
@@ -477,14 +472,14 @@ def paged_decode_attention(
 ):
     """Batched single-token decode attention against a block-paged pool.
 
-    q: [B, H, d]; k/v: [num_blocks, block_size, KV, d]; cur_len: [] or [B];
+    q: [B, H, d]; k/v: [num_blocks, KV, block_size, d]; cur_len: [] or [B];
     block_tables: [B, max_blocks] int32 (row b maps sequence b's S tiles
     to pool blocks). CPU gathers the dense per-sequence view and runs the
     dense decode oracle — bit-identical to the unpaged path; TPU routes
     through the paged ragged kernel with one descriptor per sequence (the
     same ONE kernel carries prefill packs and decode chunks)."""
     b, h, d = q.shape
-    kvh = k.shape[2]
+    kvh = k.shape[1]
     assert h % kvh == 0, (h, kvh)
     m = _resolve(mode)
     if m == "ref":

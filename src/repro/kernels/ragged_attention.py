@@ -1,6 +1,7 @@
 """Packed variable-length (ragged) attention kernel: a flat ``[T]`` token
 batch — decode singletons and prefill chunks from different sequences mixed
-freely — against the batched ``[B, S_max, KV, hd]`` decode cache.
+freely — against the batched head-major ``[B, KV, S_max, hd]`` decode
+cache (see :mod:`repro.kernels.decode_attention` for why head-major).
 
 This is the unified-dispatch serving hot path: ONE kernel serves every mix
 of admission prefill chunks and decode steps, so the engine never has to
@@ -20,7 +21,7 @@ Each packed token ``t`` carries a descriptor pair read via scalar prefetch:
 The grid is (token, KV head, S tiles); the query-head group rides inside
 the block as a ``[G, hd]`` tile, and ``tok_slot`` indexes the cache fetch in
 the BlockSpec index map — the packed batch never materializes a gathered
-``[T, S_max, KV, hd]`` cache view. Tiles entirely past ``tok_pos`` (or
+``[T, KV, S_max, hd]`` cache view. Tiles entirely past ``tok_pos`` (or
 before the sliding window) are skipped via ``pl.when``, so decode tokens of
 short sequences stay cheap inside a long-cache pack.
 
@@ -29,8 +30,8 @@ Padding tokens (pack ragged-to-bucket tail) should point at slot 0 with
 by the caller, and the out-of-bounds scatter was already dropped upstream.
 
 :func:`paged_ragged_attention` generalizes the same kernel from a dense
-``[B, S_max, KV, hd]`` cache to a block-paged ``[num_blocks, block_size,
-KV, hd]`` pool: the descriptor indirection ``(slot, pos)`` becomes
+``[B, KV, S_max, hd]`` cache to a block-paged ``[num_blocks, KV,
+block_size, hd]`` pool: the descriptor indirection ``(slot, pos)`` becomes
 ``(block, offset)`` by routing the BlockSpec's cache fetch through a
 ``[R, max_blocks]`` block table — S tile ``si`` of sequence row ``r``
 streams from pool block ``block_tables[r, si]`` instead of cache row
@@ -52,134 +53,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import _online_softmax_update
-
-NEG_INF = -1e30
+from repro.kernels.decode_attention import attend_tile, split_refs
 
 
-def _ragged_kernel(
-    slot_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-    block_s: int, s_steps: int, window: int
-):
-    si = pl.program_id(2)
-
-    @pl.when(si == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
+def _ragged_kernel(slot_ref, pos_ref, *refs, quant: bool, **kw):
     # this token's absolute position; keys at p <= pos are valid (its own
     # K/V were scattered at pos before the kernel ran)
-    pos = pos_ref[pl.program_id(0)]
-
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [G, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [bs, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # [bs, d]
-        d = q.shape[-1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (d**-0.5)
-        kpos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos <= pos
-        if window:
-            valid &= kpos > pos - window
-        s = jnp.where(valid, s, NEG_INF)
-        # zero rows of v that can't contribute (overhang reads are undefined)
-        vpos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-        v_ok = vpos <= pos
-        if window:
-            v_ok &= vpos > pos - window
-        v = jnp.where(v_ok, v, 0.0)
-        _online_softmax_update(s, v, m_ref, l_ref, acc_ref)
-
-    # skip tiles entirely past the token's position (and before the window)
-    live = si * block_s <= pos
-    if window:
-        live &= (si + 1) * block_s > pos - window
-    pl.when(live)(_compute)
-
-    @pl.when(si == s_steps - 1)
-    def _flush():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+    attend_tile(pos_ref[pl.program_id(0)], *split_refs(refs, quant), **kw)
 
 
-def _ragged_kernel_q8(
-    slot_ref, pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref,
-    l_ref, acc_ref, *, block_s: int, s_steps: int, window: int
-):
-    """Quantized-cache variant: K/V tiles arrive in the narrow store dtype
-    (int8 or float8_e4m3fn — the widen below is dtype-generic) with
-    per-(position, head) f32 scale rows riding the same index map; both
-    widen in-register after the VMEM load — no dequantized f32 cache copy
-    ever exists in HBM."""
-    si = pl.program_id(2)
-
-    @pl.when(si == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[pl.program_id(0)]
-
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [G, d]
-        # in-register dequant: int8 tile * its per-row scale column
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, :, 0, :]  # [bs, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, :, 0, :]  # [bs, d]
-        d = q.shape[-1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (d**-0.5)
-        kpos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos <= pos
-        if window:
-            valid &= kpos > pos - window
-        s = jnp.where(valid, s, NEG_INF)
-        vpos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-        v_ok = vpos <= pos
-        if window:
-            v_ok &= vpos > pos - window
-        v = jnp.where(v_ok, v, 0.0)
-        _online_softmax_update(s, v, m_ref, l_ref, acc_ref)
-
-    live = si * block_s <= pos
-    if window:
-        live &= (si + 1) * block_s > pos - window
-    pl.when(live)(_compute)
-
-    @pl.when(si == s_steps - 1)
-    def _flush():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-def _paged_kernel(
-    seq_ref, pos_ref, btab_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-    acc_ref, *, block_s: int, s_steps: int, window: int
-):
+def _paged_kernel(seq_ref, pos_ref, btab_ref, *refs, quant: bool, **kw):
     # same compute as the dense-cache kernel: the paging lives entirely in
     # the BlockSpec index map (tile si already holds the positions
     # [si*block_s, (si+1)*block_s) of this token's sequence), and the block
-    # table itself is only consumed there — the body never sees it
-    _ragged_kernel(
-        seq_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-        block_s=block_s, s_steps=s_steps, window=window,
-    )
-
-
-def _paged_kernel_q8(
-    seq_ref, pos_ref, btab_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-    m_ref, l_ref, acc_ref, *, block_s: int, s_steps: int, window: int
-):
-    # paged + quantized: the scale pools route through the SAME block-table
-    # index map as their payload pools, so a COW-shared block's scales are
-    # definitionally the ones fetched with it
-    _ragged_kernel_q8(
-        seq_ref, pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref,
-        l_ref, acc_ref, block_s=block_s, s_steps=s_steps, window=window,
-    )
+    # table itself is only consumed there — the body never sees it. Scale
+    # pools route through the SAME index map as their payload pools, so a
+    # COW-shared block's scales are definitionally the ones fetched with it
+    attend_tile(pos_ref[pl.program_id(0)], *split_refs(refs, quant), **kw)
 
 
 @functools.partial(
@@ -198,23 +88,23 @@ def ragged_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """q: [T, KV, G, d] packed queries; k/v: [B, S_max, KV, d] batched cache;
+    """q: [T, KV, G, d] packed queries; k/v: [B, KV, S_max, d] batched cache;
     tok_slot/tok_pos: [T] int32 per-token descriptors. With ``k_scale``/
-    ``v_scale`` ([B, S_max, KV, 1] f32) the cache may be int8 — tiles
+    ``v_scale`` ([B, KV, S_max] f32) the cache may be int8 — tiles
     dequantize in-register inside the kernel.
 
     Returns [T, KV, G, d] attention outputs for every packed token."""
     t, kvh, g, d = q.shape
-    s_max = k.shape[1]
+    s_max = k.shape[2]
     s_steps = pl.cdiv(s_max, block_s)
     grid = (t, kvh, s_steps)
     quant = k_scale is not None
     # the slot indirection lives in the index map: each token's K/V
-    # tiles stream straight from its cache row, no [T, S, KV, d]
+    # tiles stream straight from its cache row, no [T, KV, S, d]
     # gather ever exists
     kv_spec = pl.BlockSpec(
-        (1, block_s, 1, d),
-        lambda ti, hi, si, slots, poss: (slots[ti], si, hi, 0),
+        (1, 1, block_s, d),
+        lambda ti, hi, si, slots, poss: (slots[ti], hi, si, 0),
     )
     in_specs = [
         pl.BlockSpec((1, 1, g, d), lambda ti, hi, si, slots, poss: (ti, hi, 0, 0)),
@@ -222,8 +112,8 @@ def ragged_attention(
     ]
     if quant:
         scale_spec = pl.BlockSpec(
-            (1, block_s, 1, 1),
-            lambda ti, hi, si, slots, poss: (slots[ti], si, hi, 0),
+            (1, kvh, block_s),
+            lambda ti, hi, si, slots, poss: (slots[ti], 0, si),
         )
         in_specs += [scale_spec, kv_spec, scale_spec]
     else:
@@ -244,7 +134,6 @@ def ragged_attention(
 
     tok_slot = jnp.asarray(tok_slot, jnp.int32)
     tok_pos = jnp.asarray(tok_pos, jnp.int32)
-    kern = _ragged_kernel_q8 if quant else _ragged_kernel
     operands = (
         (tok_slot, tok_pos, q, k, k_scale, v, v_scale)
         if quant
@@ -252,7 +141,8 @@ def ragged_attention(
     )
     return pl.pallas_call(
         functools.partial(
-            kern, block_s=block_s, s_steps=s_steps, window=window
+            _ragged_kernel, quant=quant, block_s=block_s, s_steps=s_steps,
+            window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, kvh, g, d), q.dtype),
@@ -279,32 +169,35 @@ def paged_ragged_attention(
 ) -> jax.Array:
     """Packed ragged attention against a block-paged KV pool.
 
-    q: [T, KV, G, d] packed queries; k/v: [num_blocks, block_size, KV, d]
+    q: [T, KV, G, d] packed queries; k/v: [num_blocks, KV, block_size, d]
     pool; tok_seq/tok_pos: [T] int32 — token t belongs to sequence row
     ``tok_seq[t]`` of ``block_tables`` at absolute position ``tok_pos[t]``;
     block_tables: [R, max_blocks] int32 mapping (sequence row, S tile) to a
     pool block (out-of-range sentinel = unallocated). The S tile size IS
     the pool's block_size — the pool layout already tiled the cache for
     the kernel, so no extra blocking choice exists on this path. With
-    ``k_scale``/``v_scale`` ([num_blocks, block_size, KV, 1] f32 scale
+    ``k_scale``/``v_scale`` ([num_blocks, KV, block_size] f32 scale
     pools) the payload pools may be int8: the scales ride the SAME
     block-table index map, so a COW-shared block always travels with the
     scales that describe it.
 
     Returns [T, KV, G, d] attention outputs for every packed token."""
     t, kvh, g, d = q.shape
-    nb, block_s = k.shape[0], k.shape[1]
+    nb, block_s = k.shape[0], k.shape[2]
     s_steps = block_tables.shape[1]
     grid = (t, kvh, s_steps)
     quant = k_scale is not None
 
-    def _kv_map(ti, hi, si, seqs, poss, btab):
+    def _block(ti, si, seqs, btab):
         # (slot, pos) -> (block, offset): the tile's pool block comes from
         # the sequence's table; clamp the unallocated sentinel in-range
         # (those tiles are masked dead by the position bound anyway)
-        return (jnp.minimum(btab[seqs[ti], si], nb - 1), 0, hi, 0)
+        return jnp.minimum(btab[seqs[ti], si], nb - 1)
 
-    kv_spec = pl.BlockSpec((1, block_s, 1, d), _kv_map)
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_s, d),
+        lambda ti, hi, si, seqs, poss, btab: (_block(ti, si, seqs, btab), hi, 0, 0),
+    )
     in_specs = [
         pl.BlockSpec(
             (1, 1, g, d), lambda ti, hi, si, seqs, poss, btab: (ti, hi, 0, 0)
@@ -312,7 +205,10 @@ def paged_ragged_attention(
         kv_spec,
     ]
     if quant:
-        scale_spec = pl.BlockSpec((1, block_s, 1, 1), _kv_map)
+        scale_spec = pl.BlockSpec(
+            (1, kvh, block_s),
+            lambda ti, hi, si, seqs, poss, btab: (_block(ti, si, seqs, btab), 0, 0),
+        )
         in_specs += [scale_spec, kv_spec, scale_spec]
     else:
         in_specs.append(kv_spec)
@@ -333,7 +229,6 @@ def paged_ragged_attention(
     tok_seq = jnp.asarray(tok_seq, jnp.int32)
     tok_pos = jnp.asarray(tok_pos, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
-    kern = _paged_kernel_q8 if quant else _paged_kernel
     operands = (
         (tok_seq, tok_pos, block_tables, q, k, k_scale, v, v_scale)
         if quant
@@ -341,7 +236,8 @@ def paged_ragged_attention(
     )
     return pl.pallas_call(
         functools.partial(
-            kern, block_s=block_s, s_steps=s_steps, window=window
+            _paged_kernel, quant=quant, block_s=block_s, s_steps=s_steps,
+            window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, kvh, g, d), q.dtype),

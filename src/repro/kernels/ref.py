@@ -122,20 +122,20 @@ def decode_attention(
 ) -> jax.Array:
     """Single-token grouped decode attention oracle.
 
-    q: [B, KV, G, d]; k/v: [B, S_max, KV, d]; cur_len: [] or [B] tokens
-    already cached (the new token was scattered at index cur_len, so key
-    position t is valid iff t <= cur_len). Optional ``k_scale``/``v_scale``
-    ([B, S_max, KV] f32) dequantize int8 K/V rows in-math — garbage scales
+    q: [B, KV, G, d]; k/v: [B, KV, S_max, d] (head-major); cur_len: [] or
+    [B] tokens already cached (the new token was scattered at index
+    cur_len, so key position t is valid iff t <= cur_len). Optional
+    ``k_scale``/``v_scale`` ([B, KV, S_max] f32) dequantize int8 K/V rows in-math — garbage scales
     at invalid positions are as harmless as garbage K/V (masked lanes).
     Returns [B, KV, G, d] in f32 softmax math, cast back to q.dtype.
     """
     k = dequant_kv(k, k_scale, q.dtype)
     v = dequant_kv(v, v_scale, q.dtype)
     b, kvh, g, d = q.shape
-    s_max = k.shape[1]
+    s_max = k.shape[2]
     scale = d**-0.5
     scores = (
-        jnp.einsum("bkgd,btkd->bkgt", q, k.astype(q.dtype)).astype(jnp.float32)
+        jnp.einsum("bkgd,bktd->bkgt", q, k.astype(q.dtype)).astype(jnp.float32)
         * scale
     )  # [B,KV,G,S]
     kpos = jnp.arange(s_max)[None, :]
@@ -145,7 +145,7 @@ def decode_attention(
         valid &= kpos > cur - window
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bkgt,btkd->bkgd", probs, v.astype(q.dtype))
+    return jnp.einsum("bkgt,bktd->bkgd", probs, v.astype(q.dtype))
 
 
 def ragged_valid_mask(
@@ -185,10 +185,10 @@ def ragged_attention(
     """Packed variable-length attention oracle (the unified-dispatch path).
 
     q: [T, KV, G, d] packed query tokens (decode singletons and prefill
-    chunks mixed); k/v: [B, S_max, KV, d] batched cache with the packed
-    tokens' K/V already scattered at (tok_slot, tok_pos); tok_slot/tok_pos:
-    [T] int32; ``valid`` optionally passes a precomputed
-    :func:`ragged_valid_mask`; ``k_scale``/``v_scale`` ([B, S_max, KV] f32)
+    chunks mixed); k/v: [B, KV, S_max, d] head-major batched cache with
+    the packed tokens' K/V already scattered at (tok_slot, tok_pos);
+    tok_slot/tok_pos: [T] int32; ``valid`` optionally passes a precomputed
+    :func:`ragged_valid_mask`; ``k_scale``/``v_scale`` ([B, KV, S_max] f32)
     dequantize int8 caches per row. Returns [T, KV, G, d] in f32 softmax
     math, cast back to q.dtype.
 
@@ -203,21 +203,21 @@ def ragged_attention(
     k = dequant_kv(k, k_scale, q.dtype)
     v = dequant_kv(v, v_scale, q.dtype)
     t, kvh, g, d = q.shape
-    b, s_max = k.shape[0], k.shape[1]
+    b, s_max = k.shape[0], k.shape[2]
     scale = d**-0.5
     if valid is None:
         valid = ragged_valid_mask(tok_slot, tok_pos, b, s_max, window)
     # explicit [KV]-batched [T·G, d] @ [d, B·S] matmuls: XLA CPU lowers this
     # shape well at every pack size (the equivalent 5-D einsum does not)
     qf = q.transpose(1, 0, 2, 3).reshape(kvh, t * g, d).astype(jnp.float32)
-    kf = k.transpose(2, 0, 1, 3).reshape(kvh, b * s_max, d).astype(jnp.float32)
+    kf = k.transpose(1, 0, 2, 3).reshape(kvh, b * s_max, d).astype(jnp.float32)
     scores = jnp.einsum("hqd,hsd->hqs", qf, kf) * scale  # [KV, T*G, B*S]
     valid_tg = jnp.broadcast_to(
         valid.reshape(t, 1, b * s_max), (t, g, b * s_max)
     ).reshape(t * g, b * s_max)
     scores = jnp.where(valid_tg[None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    vf = v.transpose(2, 0, 1, 3).reshape(kvh, b * s_max, d).astype(jnp.float32)
+    vf = v.transpose(1, 0, 2, 3).reshape(kvh, b * s_max, d).astype(jnp.float32)
     out = jnp.einsum("hqs,hsd->hqd", probs, vf)  # [KV, T*G, d]
     return out.reshape(kvh, t, g, d).transpose(1, 0, 2, 3).astype(q.dtype)
 
@@ -270,11 +270,12 @@ def mla_ragged_attention(
 def paged_gather(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     """Materialize dense cache rows from a block-paged pool.
 
-    pool: [num_blocks, block_size, ...]; block_tables: [R, max_blocks]
-    int32 (out-of-range sentinel = unallocated). Returns [R, max_blocks *
-    block_size, ...] — exactly the dense ``[B, S_max, ...]`` cache layout,
-    where position ``p`` of row ``r`` is ``pool[block_tables[r, p //
-    block_size], p % block_size]``.
+    pool: [num_blocks, KV, block_size, ...] (K/V payloads carry a trailing
+    head_dim, scale pools none); block_tables: [R, max_blocks] int32
+    (out-of-range sentinel = unallocated). Returns [R, KV, max_blocks *
+    block_size, ...] — exactly the dense head-major ``[B, KV, S_max, ...]``
+    cache layout, where position ``p`` of row ``r`` is
+    ``pool[block_tables[r, p // block_size], :, p % block_size]``.
 
     Unallocated table entries clamp to the last real block, so their
     positions hold arbitrary (finite) pool contents — every consumer below
@@ -285,10 +286,11 @@ def paged_gather(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     Pallas path (``ragged_attention.paged_ragged_attention``) consumes the
     pool directly through its BlockSpec index map and never builds it.
     """
-    nb, bs = pool.shape[0], pool.shape[1]
+    nb, kvh, bs = pool.shape[:3]
     r, maxb = block_tables.shape
-    view = pool[jnp.minimum(block_tables, nb - 1)]  # [R, maxb, bs, ...]
-    return view.reshape(r, maxb * bs, *pool.shape[2:])
+    view = pool[jnp.minimum(block_tables, nb - 1)]  # [R, maxb, KV, bs, ...]
+    view = jnp.moveaxis(view, 1, 2)  # [R, KV, maxb, bs, ...]
+    return view.reshape(r, kvh, maxb * bs, *pool.shape[3:])
 
 
 def paged_decode_attention(
@@ -305,7 +307,7 @@ def paged_decode_attention(
     """Paged single-token decode oracle: gather the per-sequence dense view
     from the pool, then the EXACT dense decode oracle — the paged engine's
     greedy streams stay bit-identical to the slot-cache engine on CPU.
-    Scale pools ([num_blocks, block_size, KV] f32) ride the SAME gather
+    Scale pools ([num_blocks, KV, block_size] f32) ride the SAME gather
     (``paged_gather`` is trailing-dim agnostic), so scales travel with their
     blocks through tables, COW sharing and re-homing by construction."""
     return decode_attention(

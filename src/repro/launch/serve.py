@@ -25,6 +25,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.common.utils import enable_compile_cache
 from repro.configs import get_arch
 from repro.core.modes import Mode
 from repro.models import LM
@@ -184,13 +185,14 @@ def main() -> None:
     if admission_on and args.cluster_mode == "single":
         ap.error("--max-queue/--deadline-s need a cluster mode (admission "
                  "control lives at the cluster layer)")
+    enable_compile_cache()
 
     def build(arch: str, seed: int):
         cfg = get_arch(arch)
         if args.reduced:
             cfg = cfg.reduced()
         model = LM(cfg)
-        return cfg, model, model.init(jax.random.key(seed))
+        return cfg, model, model.init_params(seed)
 
     named = None  # {name: (cfg, LM, params)} when --model pairs were given
     if args.model is not None:

@@ -10,10 +10,17 @@ it never materializes the full S×S score matrix — it scans KV blocks
 carrying the online (max, sum, acc) triple. Decode dispatches through
 ``repro.kernels.ops.decode_attention`` (grouped oracle on CPU, the batched
 Pallas decode kernel on TPU).
+
+KV caches are head-major: ``[B, KV, S_max, hd]`` per layer (and
+``[num_blocks, KV, block_size, hd]`` for the paged pool), so every kernel
+tile is a 2-D ``(positions, head_dim)`` slab in the TPU's tiled minor dims.
+Under merge-mode tensor parallelism the attention kernels run per KV-head
+shard inside a ``shard_map`` (:func:`_per_head`), never on gathered K/V.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -237,13 +244,15 @@ def attention_apply(
     *,
     impl: Optional[str] = None,
     return_kv: bool = False,
+    mesh_info=None,
 ):
     """Self-attention over a full sequence (train / prefill).
 
     x: [B, S, d]; positions: [S] or [B, S]. With ``return_kv`` also returns
-    the post-rope (k, v) [B,S,KV,hd] — exactly the decode cache layout,
-    enabling prefill-into-cache. On TPU the full-attention window-free case
-    routes to the GQA-native Pallas flash kernel.
+    the post-rope (k, v) head-major [B,KV,S,hd] — exactly the decode cache
+    layout, enabling prefill-into-cache. On TPU the full-attention
+    window-free case routes to the GQA-native Pallas flash kernel — per
+    KV-head shard under merge-mode ``mesh_info`` (:func:`_per_head`).
     """
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     impl = impl or cfg.attn_impl
@@ -258,7 +267,8 @@ def attention_apply(
         positions = positions[None]
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    kv_cache = (k, v) if return_kv else None
+    # head-major [B,KV,S,hd]: the cache layout and the flash kernel's input
+    kv_cache = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)) if return_kv else None
 
     # Pallas GQA flash kernel on TPU, but only on the prefill path
     # (return_kv=True): pallas_call has no VJP, so the training forward
@@ -268,10 +278,12 @@ def attention_apply(
         and jax.default_backend() == "tpu"
         and not cfg.sliding_window
     ):
-        # [B,S,H,hd] -> [B,H,S,d] / [B,KV,S,d] for the Pallas GQA kernel
-        o = ops.gqa_flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=True,
+        # [B,S,H,hd] -> [B,H,S,d] for the Pallas GQA kernel
+        o = _per_head(
+            mesh_info,
+            functools.partial(ops.gqa_flash_attention, causal=True),
+            dict(q=q.transpose(0, 2, 1, 3), k=kv_cache[0], v=kv_cache[1]),
+            {},
         ).transpose(0, 2, 1, 3)
     elif impl == "dense":
         o = dense_attention(q, k, v, causal=True, window=cfg.sliding_window)
@@ -304,14 +316,14 @@ def attention_decode(
 ):
     """One decode step.
 
-    x: [B, 1, d]; cache_k/v: [B, S_max, KV, hd]; cur_len: [] or [B] tokens
+    x: [B, 1, d]; cache_k/v: [B, KV, S_max, hd]; cur_len: [] or [B] tokens
     already in the cache. Returns (out [B,1,d], new_k, new_v). With
     ``mesh_info`` the step runs head-sharded over the ``model`` axis
     (merge-mode serving): q and the KV cache split on their head dims, the
     per-shard partial outputs of the ``wo`` contraction all-reduce.
 
     With ``block_tables`` ([B, max_blocks] int32) the cache arguments are
-    instead a block-paged pool ``[num_blocks, block_size, KV, hd]``
+    instead a block-paged pool ``[num_blocks, KV, block_size, hd]``
     (:mod:`repro.serve.kv_pool`): the new K/V scatter lands at the
     sequence's ``(block, offset)`` for position ``cur_len`` (an
     unallocated-sentinel table entry drops the write — inert slots never
@@ -319,8 +331,8 @@ def attention_decode(
     ``ops.paged_decode_attention``, whose CPU path is bit-identical to the
     dense gather.
 
-    With ``k_scale``/``v_scale`` ([B, S_max, KV] — or [num_blocks,
-    block_size, KV] paged — f32) the cache stores quantized rows: the new
+    With ``k_scale``/``v_scale`` ([B, KV, S_max] — or [num_blocks, KV,
+    block_size] paged — f32) the cache stores quantized rows: the new
     token's K/V quantize per-(position, head) row at insert (O(written
     rows), never a cache-sized requant), the scales scatter alongside the
     payloads, and attention dequantizes inside the kernel. Returns a
@@ -337,8 +349,8 @@ def attention_decode(
     q = _head_constraint(q, mesh_info, 2)
     k = _head_constraint(k, mesh_info, 2)
     v = _head_constraint(v, mesh_info, 2)
-    cache_k = _head_constraint(cache_k, mesh_info, 2)
-    cache_v = _head_constraint(cache_v, mesh_info, 2)
+    cache_k = _head_constraint(cache_k, mesh_info, 1)
+    cache_v = _head_constraint(cache_v, mesh_info, 1)
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.norm_eps)
         k = rms_norm(k, params["k_norm"], cfg.norm_eps)
@@ -353,43 +365,49 @@ def attention_decode(
         v, vs = quantize_rows(v, cache_v.dtype)
 
     if block_tables is None:
-        # scatter the new k/v at cur_len
-        cache_k = _scatter_step(cache_k, k, cur_len)
-        cache_v = _scatter_step(cache_v, v, cur_len)
+        # scatter the new k/v at cur_len on the head-major position axis
+        cache_k = _scatter_step(cache_k, k.transpose(0, 2, 1, 3), cur_len, 2)
+        cache_v = _scatter_step(cache_v, v.transpose(0, 2, 1, 3), cur_len, 2)
         if quant:
-            k_scale = _scatter_step(k_scale, ks, cur_len)
-            v_scale = _scatter_step(v_scale, vs, cur_len)
+            k_scale = _scatter_step(k_scale, ks.transpose(0, 2, 1), cur_len, 2)
+            v_scale = _scatter_step(v_scale, vs.transpose(0, 2, 1), cur_len, 2)
 
         # grouped decode attention: never expands the cache to H heads
         # (materializing [B,S,H,hd] per layer is a groups× transient blowup
         # at 32k context); cache may be int8/f8 storage — the kernel widens
         # per-tile in-register, so no dequantized cache copy exists
-        o = ops.decode_attention(
-            q[:, 0], cache_k, cache_v, cur_len, window=cfg.sliding_window,
-            k_scale=k_scale, v_scale=v_scale,
+        o = _per_head(
+            mesh_info,
+            functools.partial(ops.decode_attention, window=cfg.sliding_window),
+            dict(q=q[:, 0], k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
+            dict(cur_len=cur_len),
         )[:, None]  # [B,1,H,hd]
     else:
         # paged: (slot, cur_len) -> (block, offset) through the sequence's
         # table row; an unallocated sentinel entry is out of pool range and
         # the write drops (inert/finished slots never corrupt a block that
         # was reassigned to another request)
-        bs = cache_k.shape[1]
+        bs = cache_k.shape[2]
         p = pos[:, 0]
         blk = block_tables[
             jnp.arange(b), jnp.minimum(p // bs, block_tables.shape[1] - 1)
         ]
-        cache_k = cache_k.at[blk, p % bs].set(
+        cache_k = cache_k.at[blk, :, p % bs].set(
             k[:, 0].astype(cache_k.dtype), mode="drop"
         )
-        cache_v = cache_v.at[blk, p % bs].set(
+        cache_v = cache_v.at[blk, :, p % bs].set(
             v[:, 0].astype(cache_v.dtype), mode="drop"
         )
         if quant:
-            k_scale = k_scale.at[blk, p % bs].set(ks[:, 0], mode="drop")
-            v_scale = v_scale.at[blk, p % bs].set(vs[:, 0], mode="drop")
-        o = ops.paged_decode_attention(
-            q[:, 0], cache_k, cache_v, cur_len, block_tables,
-            window=cfg.sliding_window, k_scale=k_scale, v_scale=v_scale,
+            k_scale = k_scale.at[blk, :, p % bs].set(ks[:, 0], mode="drop")
+            v_scale = v_scale.at[blk, :, p % bs].set(vs[:, 0], mode="drop")
+        o = _per_head(
+            mesh_info,
+            functools.partial(
+                ops.paged_decode_attention, window=cfg.sliding_window
+            ),
+            dict(q=q[:, 0], k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
+            dict(cur_len=cur_len, block_tables=block_tables),
         )[:, None]
     out = jnp.einsum("bshk,hkd->bsd", o, qweight(params["wo"], o.dtype))
     if quant:
@@ -415,7 +433,7 @@ def attention_packed(
     """Packed variable-length step: any mix of decode singletons and prefill
     chunks as ONE flat token batch (the unified serving dispatch).
 
-    x: [T, d] packed hidden states; cache_k/v: [B, S_max, KV, hd];
+    x: [T, d] packed hidden states; cache_k/v: [B, KV, S_max, hd];
     tok_slot/tok_pos: [T] int32 — token t belongs to cache slot
     ``tok_slot[t]`` at absolute position ``tok_pos[t]``; ``valid``
     optionally passes the precomputed per-pack attention mask (shared by
@@ -433,7 +451,7 @@ def attention_packed(
     slot pool. Scatters still land in the full cache.
 
     With ``block_tables`` ([B, max_blocks] int32) the cache arguments are
-    a block-paged pool ``[num_blocks, block_size, KV, hd]`` and the
+    a block-paged pool ``[num_blocks, KV, block_size, hd]`` and the
     ``(slot, pos)`` indirection generalizes to ``(block, offset)``: the
     fused scatter routes through the token's table row (bucket-padding
     positions ≥ max_blocks*block_size map to the out-of-range sentinel
@@ -456,8 +474,8 @@ def attention_packed(
     q = _head_constraint(q, mesh_info, 1)
     k = _head_constraint(k, mesh_info, 1)
     v = _head_constraint(v, mesh_info, 1)
-    cache_k = _head_constraint(cache_k, mesh_info, 2)
-    cache_v = _head_constraint(cache_v, mesh_info, 2)
+    cache_k = _head_constraint(cache_k, mesh_info, 1)
+    cache_v = _head_constraint(cache_v, mesh_info, 1)
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.norm_eps)
         k = rms_norm(k, params["k_norm"], cfg.norm_eps)
@@ -472,11 +490,11 @@ def attention_packed(
     if block_tables is None:
         # one fused scatter for the whole pack replaces the per-admission
         # full-cache insert: O(T) rows written, never a cache-sized copy
-        cache_k = cache_k.at[glob_slot, pos].set(k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[glob_slot, pos].set(v.astype(cache_v.dtype), mode="drop")
+        cache_k = cache_k.at[glob_slot, :, pos].set(k.astype(cache_k.dtype), mode="drop")
+        cache_v = cache_v.at[glob_slot, :, pos].set(v.astype(cache_v.dtype), mode="drop")
         if quant:
-            k_scale = k_scale.at[glob_slot, pos].set(ks, mode="drop")
-            v_scale = v_scale.at[glob_slot, pos].set(vs, mode="drop")
+            k_scale = k_scale.at[glob_slot, :, pos].set(ks, mode="drop")
+            v_scale = v_scale.at[glob_slot, :, pos].set(vs, mode="drop")
 
         if pack_slots is None:
             att_k, att_v = cache_k, cache_v
@@ -485,10 +503,11 @@ def attention_packed(
             att_k, att_v = cache_k[pack_slots], cache_v[pack_slots]
             att_ks = None if k_scale is None else k_scale[pack_slots]
             att_vs = None if v_scale is None else v_scale[pack_slots]
-        o = ops.ragged_attention(
-            q, att_k, att_v, tok_slot, pos,
-            window=cfg.sliding_window, valid=valid,
-            k_scale=att_ks, v_scale=att_vs,
+        o = _per_head(
+            mesh_info,
+            functools.partial(ops.ragged_attention, window=cfg.sliding_window),
+            dict(q=q, k=att_k, v=att_v, k_scale=att_ks, v_scale=att_vs),
+            dict(tok_slot=tok_slot, tok_pos=pos, valid=valid),
         )  # [T, H, hd]
     else:
         # paged pool: same fused scatter through the (block, offset)
@@ -496,24 +515,28 @@ def attention_packed(
         # out-of-range sentinel explicitly — clamping the table index and
         # letting a real block id through would corrupt offset 0 of a live
         # block; mode="drop" needs the OOB id to survive to the scatter
-        bs = cache_k.shape[1]
+        bs = cache_k.shape[2]
         maxb = block_tables.shape[1]
         nb = cache_k.shape[0]
         bidx = jnp.minimum(pos // bs, maxb - 1)
         blk = jnp.where(pos < maxb * bs, block_tables[glob_slot, bidx], nb)
-        cache_k = cache_k.at[blk, pos % bs].set(k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[blk, pos % bs].set(v.astype(cache_v.dtype), mode="drop")
+        off = pos % bs
+        cache_k = cache_k.at[blk, :, off].set(k.astype(cache_k.dtype), mode="drop")
+        cache_v = cache_v.at[blk, :, off].set(v.astype(cache_v.dtype), mode="drop")
         if quant:
-            k_scale = k_scale.at[blk, pos % bs].set(ks, mode="drop")
-            v_scale = v_scale.at[blk, pos % bs].set(vs, mode="drop")
+            k_scale = k_scale.at[blk, :, off].set(ks, mode="drop")
+            v_scale = v_scale.at[blk, :, off].set(vs, mode="drop")
 
         att_btab = (
             block_tables if pack_slots is None else block_tables[pack_slots]
         )
-        o = ops.paged_ragged_attention(
-            q, cache_k, cache_v, tok_slot, pos, att_btab,
-            window=cfg.sliding_window, valid=valid,
-            k_scale=k_scale, v_scale=v_scale,
+        o = _per_head(
+            mesh_info,
+            functools.partial(
+                ops.paged_ragged_attention, window=cfg.sliding_window
+            ),
+            dict(q=q, k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
+            dict(tok_seq=tok_slot, tok_pos=pos, block_tables=att_btab, valid=valid),
         )  # [T, H, hd]
     out = jnp.einsum("thk,hkd->td", o, qweight(params["wo"], o.dtype))
     if quant:
@@ -521,8 +544,13 @@ def attention_packed(
     return out, cache_k, cache_v
 
 
-def _scatter_step(cache: jax.Array, new: jax.Array, cur_len: jax.Array) -> jax.Array:
-    """Write new [B,1,...] into cache [B,S,...] at position cur_len (per-batch).
+def _scatter_step(
+    cache: jax.Array, new: jax.Array, cur_len: jax.Array, axis: int = 1
+) -> jax.Array:
+    """Write ``new`` (size 1 on ``axis``) into ``cache`` at position
+    cur_len (per-batch) of its position axis ``axis`` — 1 for a
+    position-major ``[B,S,...]`` latent cache, 2 for a head-major
+    ``[B,KV,S,hd]`` K/V cache or its ``[B,KV,S]`` scales.
 
     Scalar ``cur_len`` (all sequences aligned — the dry-run decode cells)
     uses one dynamic_update_slice; per-batch lengths use a vmapped
@@ -531,14 +559,46 @@ def _scatter_step(cache: jax.Array, new: jax.Array, cur_len: jax.Array) -> jax.A
     """
     cur_len = jnp.asarray(cur_len)
     if cur_len.ndim == 0:
-        start = (0, cur_len) + (0,) * (cache.ndim - 2)
+        start = [0] * cache.ndim
+        start[axis] = cur_len
         return jax.lax.dynamic_update_slice(cache, new.astype(cache.dtype), start)
     b = cache.shape[0]
     pos = jnp.broadcast_to(cur_len, (b,))
 
-    def write_row(c, n, p):  # c: [S,...], n: [1,...], p: []
-        return jax.lax.dynamic_update_slice(
-            c, n.astype(c.dtype), (p,) + (0,) * (c.ndim - 1)
-        )
+    def write_row(c, n, p):  # c: cache row (no B axis), n: its new slice
+        start = [0] * c.ndim
+        start[axis - 1] = p
+        return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), start)
 
     return jax.vmap(write_row)(cache, new, pos)
+
+
+def _per_head(mesh_info, fn, sharded: dict, replicated: dict) -> jax.Array:
+    """Call attention kernel ``fn(**sharded, **replicated)``, per KV-head
+    shard under merge-mode tensor parallelism.
+
+    A Pallas call cannot be partitioned by GSPMD: left alone it would be
+    fed all-gathered K/V. When the KV heads divide the ``model`` axis — the
+    placement ``serve_cache_shardings`` gives the cache — ``fn`` runs inside
+    a ``shard_map`` where every ``sharded`` operand (queries ``[., H, d]``,
+    head-major caches ``[., KV, S, d]``, scales ``[., KV, S]``) splits on
+    axis 1 and every ``replicated`` one (descriptors, masks, block tables)
+    is whole: each device attends its own heads against its own cache
+    shard, and the output ``[., H, d]`` stays head-sharded. ``None``
+    operands are dropped. Off-mesh (or with indivisible heads) it is a
+    plain call."""
+    sharded = {k: a for k, a in sharded.items() if a is not None}
+    replicated = {k: a for k, a in replicated.items() if a is not None}
+    ms = 1 if mesh_info is None else mesh_info.model_size
+    if ms <= 1 or sharded["k"].shape[1] % ms:
+        return fn(**sharded, **replicated)
+    from jax.sharding import PartitionSpec as P
+
+    heads = P(None, "model")
+    return jax.shard_map(
+        lambda sh, rep: fn(**sh, **rep),
+        mesh=mesh_info.mesh,
+        in_specs=(heads, P()),
+        out_specs=heads,
+        check_vma=False,
+    )(sharded, replicated)

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.dist.sharding import MeshInfo
+from repro.dist.sharding import HEAD_MAJOR_CACHE_KEYS, MeshInfo
 from repro.models import attention as attn_mod
 from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
@@ -142,6 +142,16 @@ class LM:
         else:
             raise ValueError(cfg.family)
         return params
+
+    def init_params(self, seed: int, out_shardings: Any = None) -> Params:
+        """Seeded random params, built inside ONE jitted program: each
+        leaf's f32 draw fuses with its cast to the model dtype, so no f32
+        transient of a whole stacked leaf lives beside the weights (eager
+        init holds ~2x a layer stack's bf16 bytes at full width). With
+        ``out_shardings`` (a pytree of shardings, e.g.
+        ``param_shardings``) every leaf is born on its devices — a sharded
+        model never lands on one device first."""
+        return jax.jit(self.init, out_shardings=out_shardings)(jax.random.key(seed))
 
     def param_specs(self, seed: int = 0) -> Any:
         """ShapeDtypeStruct pytree of the params (no allocation)."""
@@ -298,8 +308,14 @@ class LM:
         storage: K/V leaves store that dtype (int8 for quantized serving;
         f32 keeps the scale machinery but stays bit-identical to the plain
         path) and per-(position, head) f32 ``k_scale``/``v_scale`` leaves
-        ``[L, B, S, KV]`` live IN the cache pytree — they thread through
-        scan/donation/COW exactly like the payloads they describe."""
+        ``[L, B, KV, S]`` live IN the cache pytree — they thread through
+        scan/donation/COW exactly like the payloads they describe.
+
+        Positional K/V leaves are head-major ``[L, B, KV, S, hd]`` (the
+        layout the TPU attention kernels tile; see
+        :mod:`repro.kernels.decode_attention`); MLA latents stay
+        ``[L, B, S, r]``. Either way the position axis is ``ndim - 2`` of a
+        payload leaf."""
         cfg, dt = self.cfg, self.cache_dtype
         L = cfg.n_layers
         if kv_dtype is not None and not self.has_positional_kv:
@@ -317,14 +333,14 @@ class LM:
             if kv_dtype is not None:
                 qdt = jnp.dtype(kv_dtype)
                 return {
-                    "k": jnp.zeros((L, batch, max_len, kv, hd), qdt),
-                    "v": jnp.zeros((L, batch, max_len, kv, hd), qdt),
-                    "k_scale": jnp.ones((L, batch, max_len, kv), jnp.float32),
-                    "v_scale": jnp.ones((L, batch, max_len, kv), jnp.float32),
+                    "k": jnp.zeros((L, batch, kv, max_len, hd), qdt),
+                    "v": jnp.zeros((L, batch, kv, max_len, hd), qdt),
+                    "k_scale": jnp.ones((L, batch, kv, max_len), jnp.float32),
+                    "v_scale": jnp.ones((L, batch, kv, max_len), jnp.float32),
                 }
             return {
-                "k": jnp.zeros((L, batch, max_len, kv, hd), dt),
-                "v": jnp.zeros((L, batch, max_len, kv, hd), dt),
+                "k": jnp.zeros((L, batch, kv, max_len, hd), dt),
+                "v": jnp.zeros((L, batch, kv, max_len, hd), dt),
             }
         if cfg.family == "ssm":
             mk = (
@@ -345,26 +361,27 @@ class LM:
                 "mamba": jax.tree.map(
                     lambda leaf: jnp.zeros((L, *leaf.shape), leaf.dtype), one
                 ),
-                "attn_k": jnp.zeros((n_inv, batch, max_len, kv, hd), dt),
-                "attn_v": jnp.zeros((n_inv, batch, max_len, kv, hd), dt),
+                "attn_k": jnp.zeros((n_inv, batch, kv, max_len, hd), dt),
+                "attn_v": jnp.zeros((n_inv, batch, kv, max_len, hd), dt),
             }
         raise ValueError(cfg.family)
 
     def init_kv_pool(
         self, num_blocks: int, block_size: int, kv_dtype: Optional[Any] = None
     ) -> Params:
-        """Block-paged KV pool (zeros): ``[L, num_blocks, block_size, KV,
+        """Block-paged KV pool (zeros): ``[L, num_blocks, KV, block_size,
         hd]`` per leaf — the dense cache's ``[B, S_max]`` plane refactored
         into shared, individually-ownable blocks (paged serving,
-        :mod:`repro.serve.kv_pool`). With identity block tables (block i of
-        sequence b = b * max_blocks + i) this is a pure reshape of
-        ``init_cache(B, max_blocks * block_size)`` — paging adds an
-        indirection, not a new layout. Positional-KV families only
-        (``has_positional_kv``).
+        :mod:`repro.serve.kv_pool`), each block head-major like the dense
+        cache. With identity block tables (block i of sequence b = b *
+        max_blocks + i) a block holds positions ``[i*bs, (i+1)*bs)`` of
+        every head of ``init_cache(B, max_blocks * block_size)``'s row b —
+        paging adds an indirection, not a new tile layout. Positional-KV
+        families only (``has_positional_kv``).
 
         ``kv_dtype`` adds quantized-row storage exactly as in
-        :meth:`init_cache`: scale leaves ``[L, num_blocks, block_size,
-        KV]`` f32 are pool-shaped, so block-granular ownership (COW,
+        :meth:`init_cache`: scale leaves ``[L, num_blocks, KV,
+        block_size]`` f32 are pool-shaped, so block-granular ownership (COW,
         prefix sharing, re-homing) carries the scales with their blocks
         for free."""
         cfg, dt = self.cfg, self.cache_dtype
@@ -377,14 +394,14 @@ class LM:
             qdt = jnp.dtype(kv_dtype)
             L = cfg.n_layers
             return {
-                "k": jnp.zeros((L, num_blocks, block_size, kv, hd), qdt),
-                "v": jnp.zeros((L, num_blocks, block_size, kv, hd), qdt),
-                "k_scale": jnp.ones((L, num_blocks, block_size, kv), jnp.float32),
-                "v_scale": jnp.ones((L, num_blocks, block_size, kv), jnp.float32),
+                "k": jnp.zeros((L, num_blocks, kv, block_size, hd), qdt),
+                "v": jnp.zeros((L, num_blocks, kv, block_size, hd), qdt),
+                "k_scale": jnp.ones((L, num_blocks, kv, block_size), jnp.float32),
+                "v_scale": jnp.ones((L, num_blocks, kv, block_size), jnp.float32),
             }
         return {
-            "k": jnp.zeros((cfg.n_layers, num_blocks, block_size, kv, hd), dt),
-            "v": jnp.zeros((cfg.n_layers, num_blocks, block_size, kv, hd), dt),
+            "k": jnp.zeros((cfg.n_layers, num_blocks, kv, block_size, hd), dt),
+            "v": jnp.zeros((cfg.n_layers, num_blocks, kv, block_size, hd), dt),
         }
 
     def cache_specs(self, batch: int, max_len: int) -> Any:
@@ -402,24 +419,33 @@ class LM:
         def leaf_spec(path, leaf):
             parts: list[Any] = [None] * leaf.ndim
             used = None
+            name = getattr(path[-1], "key", None) if path else None
+            # head-major K/V [L, B, KV, S, hd] and their scales [L, B, KV, S]
+            # keep heads on 2 and positions on 3; MLA latents [L, B, S, r]
+            # and SSM state keep the position-major reading of dim 2
+            head_major = name in HEAD_MAJOR_CACHE_KEYS
+            seq = 3 if head_major else 2
             if leaf.shape[1] % dp == 0 and leaf.shape[1] >= dp:
                 parts[1] = mesh_info.batch_axes
             elif (
-                leaf.ndim >= 4  # attention caches: [L, B, S, ...]
-                and leaf.shape[2] % dp == 0
-                and leaf.shape[2] >= dp
+                leaf.ndim >= 4
+                and leaf.shape[seq] % dp == 0
+                and leaf.shape[seq] >= dp
             ):
-                parts[2] = mesh_info.batch_axes
-                used = 2
+                parts[seq] = mesh_info.batch_axes
+                used = seq
             # model-axis placement preference for attention caches
-            # [L, B, S, KV, hd]: KV heads first (clean head parallelism),
+            # [L, B, KV, S, hd]: KV heads first (clean head parallelism),
             # then the SEQUENCE dim (flash-decoding-style split-K: the
             # scores/AV contractions run shard-local + one psum, and the
             # scatter is a masked local write — no resharding copies),
             # then head_dim as the last resort.
-            order = [3, 2, leaf.ndim - 1] if leaf.ndim >= 4 else list(
-                range(leaf.ndim - 1, 1, -1)
-            )
+            if head_major:
+                order = [2, 3, leaf.ndim - 1]
+            elif leaf.ndim >= 4:
+                order = [3, 2, leaf.ndim - 1]
+            else:
+                order = list(range(leaf.ndim - 1, 1, -1))
             for i in order:
                 if i == used or i >= leaf.ndim or parts[i] is not None:
                     continue
@@ -471,7 +497,9 @@ class LM:
                 pieces = jax.tree.map(
                     lambda *ts: jnp.concatenate(ts, axis=0), *pieces_list
                 ) if len(pieces_list) > 1 else pieces_list[0]
-            cache = jax.tree.map(lambda t: pad_seq_axis(t, 2, max_len), pieces)
+            cache = jax.tree.map(
+                lambda t: pad_seq_axis(t, t.ndim - 2, max_len), pieces
+            )
         elif cfg.family == "ssm":
 
             def body(xx, blk):
@@ -502,7 +530,8 @@ class LM:
                 xx, sts = jax.lax.scan(inner, xx, gblk)
                 h = rms_norm(xx, shared["norm1"], cfg.norm_eps)
                 a, (kc, vc) = attn_mod.attention_apply(
-                    shared["attn"], cfg, h, positions, return_kv=True
+                    shared["attn"], cfg, h, positions, return_kv=True,
+                    mesh_info=self.mesh_info,
                 )
                 xx = xx + a
                 h = rms_norm(xx, shared["norm2"], cfg.norm_eps)
@@ -514,8 +543,8 @@ class LM:
                 "mamba": jax.tree.map(
                     lambda t: t.reshape(cfg.n_layers, *t.shape[2:]), mcache
                 ),
-                "attn_k": pad_seq_axis(ks, 2, max_len),
-                "attn_v": pad_seq_axis(vs, 2, max_len),
+                "attn_k": pad_seq_axis(ks, 3, max_len),
+                "attn_v": pad_seq_axis(vs, 3, max_len),
             }
         else:
             raise ValueError(cfg.family)
@@ -535,7 +564,8 @@ class LM:
             piece = {"ckv": ckv, "krope": krope}
         else:
             a, (k, v) = attn_mod.attention_apply(
-                blk["attn"], cfg, h, positions, return_kv=True
+                blk["attn"], cfg, h, positions, return_kv=True,
+                mesh_info=self.mesh_info,
             )
             piece = {"k": k, "v": v}
         x = x + a
@@ -768,16 +798,16 @@ class LM:
         from repro.kernels import ref as _ref
 
         if block_tables is None:
-            # [L, B, S_max, KV, hd] positional rows or [L, B, S_max, r]
-            # compressed latents — batch/seq axes sit in the same places
+            # [L, B, KV, S_max, hd] head-major rows or [L, B, S_max, r]
+            # compressed latents — positions sit on ndim-2 of both
             k_leaf = cache["ckv"] if cfg.mla is not None else cache["k"]
             n_rows = k_leaf.shape[1] if pack_slots is None else len(pack_slots)
-            s_max = k_leaf.shape[2]
-        else:  # pool leaf [L, NB, bs, KV, hd]: S_max = table width * block
+            s_max = k_leaf.shape[-2]
+        else:  # pool leaf [L, NB, KV, bs, hd]: S_max = table width * block
             n_rows = (
                 block_tables.shape[0] if pack_slots is None else len(pack_slots)
             )
-            s_max = block_tables.shape[1] * cache["k"].shape[2]
+            s_max = block_tables.shape[1] * cache["k"].shape[3]
         valid = _ref.ragged_valid_mask(
             tok_slot, tok_pos, n_rows, s_max, cfg.sliding_window
         )

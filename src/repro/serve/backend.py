@@ -17,7 +17,7 @@ or ``jax.device_put`` itself, so the same tick loop serves three fabrics:
   replicas never serialize through the process default device.
 * :class:`ShardedBackend` — tensor-parallel placement over a
   :class:`~repro.dist.sharding.MeshInfo` ``model`` axis: params via
-  ``param_shardings`` (attention heads partitioned), the ``[L,B,S,KV,hd]``
+  ``param_shardings`` (attention heads partitioned), the head-major ``[L,B,KV,S,hd]``
   KV cache via ``serve_cache_shardings``, tick state and host staging
   replicated. Dispatch programs are plain ``jax.jit`` — GSPMD partitions
   them from the operand shardings (merge-mode serving).

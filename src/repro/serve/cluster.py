@@ -10,7 +10,7 @@ temporal, single-device version; this module adds the spatial one):
   with per-tenant affinity. Two latency-sensitive tenants proceed
   concurrently — the paper's two independent cores, the router playing the
   scalar control core.
-* **MERGE** — ONE engine whose params and ``[L, B, S_max, KV, hd]`` KV
+* **MERGE** — ONE engine whose params and ``[L, B, KV, S_max, hd]`` KV
   cache are tensor-parallel over the ``model`` axis
   (``dist.sharding.spec_for_param`` / ``serve_cache_shardings``, attention
   heads partitioned — see ``models/attention._head_constraint``), its

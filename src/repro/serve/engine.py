@@ -1223,17 +1223,7 @@ class ServeEngine:
         for tb in tb_ladder:
             if tb in self._packed_shapes:
                 continue
-            # an all-padding pack: scatters dropped (pos = max_len), no
-            # slot sampled, cur_len passed through unchanged
-            desc = np.zeros((3, tb), np.int32)
-            desc[2] = self.max_len
-            meta = np.concatenate(
-                [
-                    self.slot_len,
-                    np.zeros(2 * self.B, np.int32),
-                    np.zeros(self._pack_width, np.int32),
-                ]
-            )
+            desc, meta = self._padding_pack(tb)
             for sm in smodes:
                 if self.paged:
                     toks, _lt, _cl, self.cache = self._packed_paged(
@@ -1307,6 +1297,57 @@ class ServeEngine:
                 )
                 jax.block_until_ready(tok)
             self._admit_shapes.add(sb)
+
+    def _padding_pack(self, tb: int) -> tuple[np.ndarray, np.ndarray]:
+        """(desc, meta) of an all-padding pack of ``tb`` tokens: scatters
+        dropped (pos = max_len), no slot sampled, cur_len passed through
+        unchanged."""
+        desc = np.zeros((3, tb), np.int32)
+        desc[2] = self.max_len
+        meta = np.concatenate(
+            [
+                self.slot_len,
+                np.zeros(2 * self.B, np.int32),
+                np.zeros(self._pack_width, np.int32),
+            ]
+        )
+        return desc, meta
+
+    def lower_dispatch(self) -> dict:
+        """The two hot dispatch programs — one greedy decode tick and the
+        widest packed T bucket (unified engines) — lowered against the live
+        operands, nothing executed: ``{"tick": Lowered, "packed":
+        Lowered}``. A caller reads what they compile to, e.g. that the
+        attention kernels are in them (``tpu_custom_call``) and no plain-XLA
+        fallback replaced them."""
+        sp = (self._spf, self._spi, self._btok, self._bval)
+        if self.paged:
+            out = {"tick": self._tick_paged.lower(
+                self.params, self.cache, self._btab, self._last_tok,
+                self._cur_len, self._lanes_idle, *sp, n_steps=1, smode=0,
+            )}
+        else:
+            out = {"tick": self._tick.lower(
+                self.params, self.cache, self._last_tok, self._cur_len,
+                self._lanes_idle, *sp, n_steps=1, smode=0,
+            )}
+        if self.unified:
+            desc, meta = (
+                self.backend.put_host(a)
+                for a in self._padding_pack(_bucket_tokens(self.prefill_budget))
+            )
+            out["packed"] = (
+                self._packed_paged.lower(
+                    self.params, self.cache, self._btab, self._last_tok,
+                    desc, meta, *sp, smode=0,
+                )
+                if self.paged
+                else self._packed.lower(
+                    self.params, self.cache, self._last_tok, desc, meta, *sp,
+                    smode=0,
+                )
+            )
+        return out
 
     def reset(self) -> None:
         """Return an IDLE engine to its just-constructed serving state.

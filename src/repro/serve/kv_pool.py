@@ -1,9 +1,9 @@
 """Block-paged KV pool: the host-side allocator behind paged serving.
 
-Dense serving reserves a worst-case ``[S_max, KV, hd]`` cache row per slot;
+Dense serving reserves a worst-case ``[KV, S_max, hd]`` cache row per slot;
 a slot serving a 40-token request pays for ``S_max`` positions. The paged
-engine instead owns ONE device pool shaped ``[L, num_blocks, block_size,
-KV, hd]`` and maps each request onto it through a per-request *block
+engine instead owns ONE device pool shaped ``[L, num_blocks, KV,
+block_size, hd]`` and maps each request onto it through a per-request *block
 table*: row ``i`` of a request's table names the pool block holding its
 positions ``[i*block_size, (i+1)*block_size)``. A request then costs
 ``ceil(total_positions / block_size)`` blocks — its actual length, rounded

@@ -2,16 +2,8 @@
 device; multi-device behaviour is exercised via subprocess helpers
 (tests/multidev/) so the dry-run's 512-device environment stays isolated."""
 
-import os
-import sys
-
 import numpy as np
 import pytest
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # pinned container lacks hypothesis: use the bundled stub
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_stubs"))
 
 
 @pytest.fixture

@@ -154,3 +154,59 @@ def test_env_var_cache_path(monkeypatch, tmp_path):
     assert t.path == p
     t.store("dotp", (1, 4096), "float32", "interpret", {"block": 512})
     assert os.path.exists(p)
+
+
+def test_default_cache_path_stays_in_checkout(monkeypatch):
+    """Without ``REPRO_AUTOTUNE_CACHE`` the winners file lives in the
+    checkout: no block size may come from a file under the home directory."""
+    from repro.common.utils import REPO_ROOT
+
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    assert Autotuner().path == str(REPO_ROOT / ".autotune.json")
+
+
+def test_enable_compile_cache_defaults_to_repo(monkeypatch):
+    """Unset ``JAX_COMPILATION_CACHE_DIR``: the entry points' cache is the
+    fixed ``<repo>/.jax_cache``."""
+    import jax
+
+    from repro.common.utils import REPO_ROOT, enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == str(REPO_ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(REPO_ROOT / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_entries_land_in_env_dir(tmp_path):
+    """Set ``JAX_COMPILATION_CACHE_DIR``: the helper keeps JAX's setting and
+    a compiled program's entry lands there (fresh process: the cache
+    directory is fixed at a process's first compile)."""
+    import subprocess
+    import sys
+
+    from repro.common.utils import REPO_ROOT
+
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+        PYTHONPATH=str(REPO_ROOT / "src"),
+    )
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.common.utils import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True,
+        timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == str(tmp_path)
+    assert os.listdir(tmp_path), "no cache entry was written"

@@ -34,8 +34,8 @@ def test_decode_kernel_vs_dense_oracle(rng, window, kv_heads):
     b, h, hd, s_max = 3, 6, 16, 40
     cur_len = np.array([0, 7, 33], np.int32)  # per-slot ragged lengths
     q = _rand(rng, (b, h, hd))
-    k = _rand(rng, (b, s_max, kv_heads, hd))
-    v = _rand(rng, (b, s_max, kv_heads, hd))
+    k = _rand(rng, (b, kv_heads, s_max, hd))  # head-major cache
+    v = _rand(rng, (b, kv_heads, s_max, hd))
 
     got = ops.decode_attention(
         q, k, v, jnp.asarray(cur_len), window=window, mode="interpret", block_s=16
@@ -50,8 +50,8 @@ def test_decode_kernel_vs_dense_oracle(rng, window, kv_heads):
         cur = int(cur_len[i])
         o = dense_attention(
             q[i][None, None],          # [1, 1, H, hd]
-            k[i, : cur + 1][None],     # [1, cur+1, KV, hd]
-            v[i, : cur + 1][None],
+            k[i, :, : cur + 1].transpose(1, 0, 2)[None],  # [1, cur+1, KV, hd]
+            v[i, :, : cur + 1].transpose(1, 0, 2)[None],
             causal=True,
             q_offset=cur,
             window=window,
@@ -69,8 +69,8 @@ def test_decode_kernel_low_precision_cache(rng):
     b, h, kv, hd, s_max = 2, 4, 2, 16, 32
     cur = jnp.asarray([5, 17], jnp.int32)
     q = _rand(rng, (b, h, hd))
-    k = _rand(rng, (b, s_max, kv, hd)).astype(jnp.bfloat16)
-    v = _rand(rng, (b, s_max, kv, hd)).astype(jnp.bfloat16)
+    k = _rand(rng, (b, kv, s_max, hd)).astype(jnp.bfloat16)
+    v = _rand(rng, (b, kv, s_max, hd)).astype(jnp.bfloat16)
     got = ops.decode_attention(q, k, v, cur, mode="interpret", block_s=16)
     expect = ops.decode_attention(q, k, v, cur, mode="ref")
     np.testing.assert_allclose(
@@ -102,8 +102,8 @@ def test_attention_decode_vs_manual_oracle(rng, qk_norm, window):
     b, s_max = 2, 24
     cur_len = np.array([4, 15], np.int32)
     # pre-existing cache contents (as if prefilled)
-    cache_k = _rand(rng, (b, s_max, cfg.n_kv_heads, cfg.head_dim))
-    cache_v = _rand(rng, (b, s_max, cfg.n_kv_heads, cfg.head_dim))
+    cache_k = _rand(rng, (b, cfg.n_kv_heads, s_max, cfg.head_dim))
+    cache_v = _rand(rng, (b, cfg.n_kv_heads, s_max, cfg.head_dim))
     x = _rand(rng, (b, 1, cfg.d_model))
 
     out, new_k, new_v = attention_decode(
@@ -125,14 +125,14 @@ def test_attention_decode_vs_manual_oracle(rng, qk_norm, window):
         cur = int(cur_len[i])
         # the new token's k/v must have landed at index cur
         np.testing.assert_allclose(
-            np.asarray(new_k[i, cur]), np.asarray(k_new[i, 0]), rtol=1e-5, atol=1e-6
+            np.asarray(new_k[i, :, cur]), np.asarray(k_new[i, 0]), rtol=1e-5, atol=1e-6
         )
-        ki = np.array(cache_k[i])  # writable copy
-        ki[cur] = np.asarray(k_new[i, 0])
+        ki = np.array(cache_k[i])  # writable copy, [KV, S, hd]
+        ki[:, cur] = np.asarray(k_new[i, 0])
         o = dense_attention(
             q[i][None],
-            jnp.asarray(ki[: cur + 1])[None],
-            new_v[i, : cur + 1][None],
+            jnp.asarray(ki[:, : cur + 1].transpose(1, 0, 2))[None],
+            new_v[i, :, : cur + 1].transpose(1, 0, 2)[None],
             causal=True,
             q_offset=cur,
             window=window,
@@ -147,8 +147,8 @@ def test_attention_decode_scalar_cur_len_matches_vector(rng):
     cfg = _tiny_cfg()
     params = attention_init(jax.random.key(1), cfg, jnp.float32)
     b, s_max = 2, 16
-    cache_k = _rand(rng, (b, s_max, cfg.n_kv_heads, cfg.head_dim))
-    cache_v = _rand(rng, (b, s_max, cfg.n_kv_heads, cfg.head_dim))
+    cache_k = _rand(rng, (b, cfg.n_kv_heads, s_max, cfg.head_dim))
+    cache_v = _rand(rng, (b, cfg.n_kv_heads, s_max, cfg.head_dim))
     x = _rand(rng, (b, 1, cfg.d_model))
     o1, k1, v1 = attention_decode(params, cfg, x, cache_k, cache_v, jnp.int32(5))
     o2, k2, v2 = attention_decode(

@@ -85,7 +85,7 @@ def test_conv2d_masked_grid_matches_ref(h):
 
 def test_ragged_attention_dispatch_no_pad():
     q = jnp.zeros((10, 6, 16), jnp.float32)  # ragged T
-    k = jnp.zeros((3, 40, 2, 16), jnp.float32)  # ragged S_max
+    k = jnp.zeros((3, 2, 40, 16), jnp.float32)  # head-major, ragged S_max
     slots = jnp.zeros((10,), jnp.int32)
     poss = jnp.zeros((10,), jnp.int32)
     prims = _top_level_primitives(
@@ -94,12 +94,13 @@ def test_ragged_attention_dispatch_no_pad():
         ),
         q, k, slots, poss,
     )
-    assert "pad" not in prims, prims
+    # the kernel reads the cache in place: no pad, no relayout copy
+    assert "pad" not in prims and "transpose" not in prims, prims
 
 
 def test_decode_attention_dispatch_no_pad():
     q = jnp.zeros((3, 6, 16), jnp.float32)
-    k = jnp.zeros((3, 40, 2, 16), jnp.float32)
+    k = jnp.zeros((3, 2, 40, 16), jnp.float32)  # head-major cache
     cur = jnp.zeros((3,), jnp.int32)
     prims = _top_level_primitives(
         lambda a, b, c: ops.decode_attention(
@@ -107,7 +108,7 @@ def test_decode_attention_dispatch_no_pad():
         ),
         q, k, cur,
     )
-    assert "pad" not in prims, prims
+    assert "pad" not in prims and "transpose" not in prims, prims
 
 
 @pytest.mark.parametrize("n", [2048, 2500, 700])  # whole, ragged, tail-only
@@ -169,7 +170,7 @@ def test_attention_decode_never_calls_repeat_kv(monkeypatch):
 
     monkeypatch.setattr(attn_mod, "_repeat_kv", boom)
     x = jnp.zeros((2, 1, cfg.d_model), jnp.float32)
-    ck = jnp.zeros((2, 16, cfg.n_kv_heads, cfg.head_dim), jnp.float32)
+    ck = jnp.zeros((2, cfg.n_kv_heads, 16, cfg.head_dim), jnp.float32)
     out, _, _ = attn_mod.attention_decode(
         params, cfg, x, ck, ck, jnp.asarray([3, 5], jnp.int32)
     )

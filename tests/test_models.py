@@ -9,7 +9,7 @@ from repro.configs import get_arch
 from repro.models import LM
 
 FAMS = [
-    "codeqwen1.5-7b",  # MHA
+    "codeqwen1.5-7b",  # GQA (4 KV heads; one when reduced)
     "qwen3-32b",  # GQA + qk_norm
     "minicpm3-4b",  # MLA (q_lora)
     "deepseek-v2-lite-16b",  # MoE + MLA + first_k_dense

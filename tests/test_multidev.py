@@ -36,7 +36,6 @@ def run_py(code: str, devices: int = 8, timeout: int = 560) -> str:
 def test_cluster_modes_collectives_elastic():
     out = run_py(
         r"""
-import repro  # noqa: F401  (installs jax 0.4.x compat shims first)
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import SpatzformerCluster, Mode, switch_mode, reshard
@@ -91,7 +90,6 @@ def test_small_mesh_multipod_dryrun_reduced_archs():
     train step — the structural multi-pod check at test scale."""
     out = run_py(
         r"""
-import repro  # noqa: F401  (installs jax 0.4.x compat shims first)
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, AxisType
 from repro.configs import get_arch, TrainConfig

@@ -83,9 +83,9 @@ def test_quantize_rows_zero_row_safe():
 # ----------------------------------------------- kernels: in-kernel dequant
 
 
-def _quant_kv(rng, b, s, kv, d):
-    k = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
+def _quant_kv(rng, b, s, kv, d):  # head-major [B, KV, S, d], scales [B, KV, S]
+    k = jnp.asarray(rng.standard_normal((b, kv, s, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, kv, s, d)), jnp.float32)
     kq, ks = quantize_rows(k, jnp.int8)
     vq, vs = quantize_rows(v, jnp.int8)
     return (kq, ks, vq, vs)
@@ -132,7 +132,7 @@ def test_paged_attention_q8_matches_dequant_oracle():
     rng = np.random.default_rng(3)
     nb, bs, kv, g, d, b = 8, 8, 2, 2, 16, 2
     q = jnp.asarray(rng.standard_normal((b, kv * g, d)), jnp.float32)
-    kq, ks, vq, vs = _quant_kv(rng, nb, bs, kv, d)  # pool layout [NB, bs, KV, d]
+    kq, ks, vq, vs = _quant_kv(rng, nb, bs, kv, d)  # pool layout [NB, KV, bs, d]
     tables = jnp.arange(nb, dtype=jnp.int32).reshape(b, nb // b)
     cur = jnp.asarray([9, 27], jnp.int32)
     ref_q8 = ops.paged_decode_attention(

@@ -24,8 +24,8 @@ def _rand(rng, shape, dtype=F32):
     return jnp.asarray(rng.standard_normal(shape), dtype)
 
 
-def _cache(rng, b, s_max, kv, d):
-    return _rand(rng, (b, s_max, kv, d)), _rand(rng, (b, s_max, kv, d))
+def _cache(rng, b, s_max, kv, d):  # head-major [B, KV, S_max, d]
+    return _rand(rng, (b, kv, s_max, d)), _rand(rng, (b, kv, s_max, d))
 
 
 def _check(q, k, v, tok_slot, tok_pos, *, window=0, block_s=16, n_real=None):
@@ -105,8 +105,8 @@ def test_prefill_chunk_is_causally_exact(rng):
     q = _rand(rng, (1, s, h, d))
     want = dense_attention(q, kseq, vseq, causal=True)  # [1, S, H, d]
 
-    kc = jnp.zeros((2, s_max, kv, d), F32).at[1, :s].set(kseq[0])
-    vc = jnp.zeros((2, s_max, kv, d), F32).at[1, :s].set(vseq[0])
+    kc = jnp.zeros((2, kv, s_max, d), F32).at[1, :, :s].set(kseq[0].transpose(1, 0, 2))
+    vc = jnp.zeros((2, kv, s_max, d), F32).at[1, :, :s].set(vseq[0].transpose(1, 0, 2))
     got = jnp.concatenate([
         ops.ragged_attention(
             q[0, st : st + 4], kc, vc,
@@ -166,7 +166,7 @@ def test_packed_step_matches_prefill_and_decode():
     # the scattered cache row equals the prefill cache
     for key in ("k", "v"):
         np.testing.assert_allclose(
-            np.asarray(cache[key][:, 1, : len(prompt)]),
-            np.asarray(one_cache[key][:, 0, : len(prompt)]),
+            np.asarray(cache[key][:, 1, :, : len(prompt)]),
+            np.asarray(one_cache[key][:, 0, :, : len(prompt)]),
             rtol=1e-5, atol=1e-5,
         )

@@ -40,8 +40,8 @@ def test_paged_kernel_matches_oracle(h, kv):
     pack (decode singletons + a prefill chunk) over a fragmented pool."""
     rng = np.random.default_rng(0)
     nb, bs, d, maxb = 7, 8, 16, 3  # rows address up to 24 positions
-    pool_k = _rand(rng, (nb, bs, kv, d))
-    pool_v = _rand(rng, (nb, bs, kv, d))
+    pool_k = _rand(rng, (nb, kv, bs, d))  # head-major blocks
+    pool_v = _rand(rng, (nb, kv, bs, d))
     # two requests with deliberately scrambled, partial tables (sentinel nb
     # marks unallocated tail entries)
     btab = jnp.asarray([[4, 1, 6], [0, 5, nb]], jnp.int32)
@@ -60,15 +60,19 @@ def test_paged_kernel_matches_oracle(h, kv):
 
 
 def test_paged_oracle_matches_dense_with_identity_tables():
-    """An identity block table makes the pool a pure reshape of the dense
+    """An identity block table makes the pool a re-blocking of the dense
     cache: the paged oracle must agree with the dense ragged oracle."""
     rng = np.random.default_rng(1)
     b, s_max, h, kv, d, bs = 2, 24, 4, 2, 16, 8
-    k = _rand(rng, (b, s_max, kv, d))
-    v = _rand(rng, (b, s_max, kv, d))
+    k = _rand(rng, (b, kv, s_max, d))
+    v = _rand(rng, (b, kv, s_max, d))
     maxb = s_max // bs
-    pool_k = k.reshape(b * maxb, bs, kv, d)
-    pool_v = v.reshape(b * maxb, bs, kv, d)
+
+    def to_pool(x):  # [B, KV, maxb*bs, d] -> [B*maxb, KV, bs, d]
+        x = x.reshape(b, kv, maxb, bs, d).transpose(0, 2, 1, 3, 4)
+        return x.reshape(b * maxb, kv, bs, d)
+
+    pool_k, pool_v = to_pool(k), to_pool(v)
     btab = jnp.arange(b * maxb, dtype=jnp.int32).reshape(b, maxb)
     tok_seq = jnp.asarray([0, 1, 1], jnp.int32)
     tok_pos = jnp.asarray([7, 13, 14], jnp.int32)
@@ -115,6 +119,46 @@ def test_paged_engine_bit_identical_to_dense(small_model):
     assert paged == dense
     # every block went back to the free list when its request finished
     assert eng.pool.free == eng.num_blocks
+
+
+def test_bf16_dense_and_paged_engines_serve():
+    """The chip serves in bf16, the config's own dtype, which ``reduced()``
+    replaces with f32: serve the reduced codeqwen config in bf16 through
+    the unified dense and paged engines — fused admission, a chunked
+    prompt longer than the prefill budget, decode to ``max_new`` — so a
+    dtype fault surfaces here before it costs chip time."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_arch("codeqwen1.5-7b").reduced(), dtype="bfloat16")
+    m = LM(cfg)
+    p = m.init_params(0)
+    assert {leaf.dtype for leaf in jax.tree.leaves(p)} == {jnp.dtype(jnp.bfloat16)}
+    prompts = _prompts(cfg, (5, 40, 17))
+    dense_eng, dense = _serve(m, p, prompts, max_new=4)
+    paged_eng, paged = _serve(m, p, prompts, max_new=4, kv_block_size=8)
+    for eng in (dense_eng, paged_eng):
+        assert all(leaf.dtype == jnp.bfloat16 for leaf in jax.tree.leaves(eng.cache))
+    assert sorted(dense) == [0, 1, 2]
+    assert all(len(toks) == 4 for toks in dense.values())
+    assert all(0 <= t < cfg.vocab_size for toks in dense.values() for t in toks)
+    assert paged == dense  # the CPU paths gather into the dense oracle
+
+
+@pytest.mark.parametrize("block", [None, 8])
+def test_lower_dispatch_programs(small_model, block):
+    """``lower_dispatch`` lowers the hot tick and packed programs against
+    the live operands without running them (the engine state is
+    untouched); off-TPU they carry the jnp oracles, not the Pallas call."""
+    cfg, m, p = small_model
+    eng = ServeEngine(m, p, batch_slots=2, max_len=64, kv_block_size=block)
+    progs = eng.lower_dispatch()
+    assert sorted(progs) == ["packed", "tick"]
+    for lowered in progs.values():
+        assert "tpu_custom_call" not in lowered.as_text()
+    eng.submit(Request(rid=0, prompt=_prompts(cfg, (6,))[0],
+                       params=SamplingParams(max_new=2)))
+    eng.run()  # the lowered-only engine still serves
+    assert [len(r.generated) for r in eng.finished] == [2]
 
 
 def test_paged_engine_cancellation_bit_identity(small_model):

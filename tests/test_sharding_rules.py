@@ -115,11 +115,12 @@ def test_analytic_counts_sane():
 
 
 def test_serve_cache_shardings_never_shard_slot_or_seq():
-    """Serving cache placement: positional caches (attention K/V, MLA
-    latent/rope) shard only PAST the sequence axis — KV heads first,
-    head_dim/rank fallback; slot (dim 1) and sequence (dim 2) stay whole
-    (the engine scatters rows at arbitrary (slot, pos) every tick).
-    Recurrent SSM leaves take their widest trailing dim."""
+    """Serving cache placement: head-major attention K/V ``[L, B, KV, S,
+    hd]`` shard KV heads first with a head_dim fallback, their scales
+    ``[L, B, KV, S]`` the heads only; MLA latent/rope ``[L, B, S, r]``
+    shard the rank. Slot (dim 1) and sequence stay whole (the engine
+    scatters rows at arbitrary (slot, pos) every tick). Recurrent SSM
+    leaves take their widest trailing dim."""
     import jax
 
     from repro.dist.sharding import serve_cache_shardings
@@ -132,18 +133,22 @@ def test_serve_cache_shardings_never_shard_slot_or_seq():
 
     f32 = np.float32
     cache = {
-        "k": jax.ShapeDtypeStruct((2, 4, 96, 2, 16), f32),  # KV heads divide
-        "v": jax.ShapeDtypeStruct((2, 4, 96, 1, 16), f32),  # GQA fallback: hd
+        "k": jax.ShapeDtypeStruct((2, 4, 2, 96, 16), f32),  # KV heads divide
+        "v": jax.ShapeDtypeStruct((2, 4, 1, 96, 16), f32),  # GQA fallback: hd
+        "k_scale": jax.ShapeDtypeStruct((2, 4, 2, 96), f32),  # with its heads
+        "v_scale": jax.ShapeDtypeStruct((2, 4, 1, 96), f32),  # never the seq
         "ckv": jax.ShapeDtypeStruct((2, 4, 96, 32), f32),  # MLA latent: rank
         "krope": jax.ShapeDtypeStruct((2, 4, 96, 8), f32),
-        "attn_k": jax.ShapeDtypeStruct((1, 4, 96, 2, 16), f32),  # hybrid pool
+        "attn_k": jax.ShapeDtypeStruct((1, 4, 2, 96, 16), f32),  # hybrid pool
         "mamba": {"conv": jax.ShapeDtypeStruct((2, 4, 3, 64), f32)},  # widest
     }
     specs = serve_cache_shardings(cache, FakeInfo())
-    assert specs["k"] == P(None, None, None, "model", None)
+    assert specs["k"] == P(None, None, "model", None, None)
     assert specs["v"] == P(None, None, None, None, "model")
+    assert specs["k_scale"] == P(None, None, "model", None)
+    assert specs["v_scale"] == P(None, None, None, None)
     # the MLA regression: dim 2 is SEQUENCE — only the rank dim may shard
     assert specs["ckv"] == P(None, None, None, "model")
     assert specs["krope"] == P(None, None, None, "model")
-    assert specs["attn_k"] == P(None, None, None, "model", None)
+    assert specs["attn_k"] == P(None, None, "model", None, None)
     assert specs["mamba"]["conv"] == P(None, None, None, "model")
