@@ -1,6 +1,5 @@
 from repro.common.utils import (
     REPO_ROOT,
-    Timer,
     enable_compile_cache,
     human_bytes,
     human_flops,
@@ -12,7 +11,6 @@ from repro.common.utils import (
 
 __all__ = [
     "REPO_ROOT",
-    "Timer",
     "enable_compile_cache",
     "human_bytes",
     "human_flops",

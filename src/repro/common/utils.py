@@ -1,10 +1,9 @@
-"""Small shared utilities: timing, humanized units, pytree accounting, and
-the persistent compilation cache of the entry points."""
+"""Small shared utilities: humanized units, pytree accounting, and the
+persistent compilation cache of the entry points."""
 
 from __future__ import annotations
 
 import os
-import time
 from pathlib import Path
 from typing import Any
 
@@ -31,26 +30,6 @@ def enable_compile_cache() -> str:
     path = str(REPO_ROOT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
-
-
-class Timer:
-    """Wall-clock timer usable as a context manager.
-
-    >>> with Timer() as t:
-    ...     work()
-    >>> t.elapsed  # seconds
-    """
-
-    def __init__(self) -> None:
-        self.elapsed = 0.0
-        self._t0 = 0.0
-
-    def __enter__(self) -> "Timer":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc: Any) -> None:
-        self.elapsed = time.perf_counter() - self._t0
 
 
 def _humanize(x: float, units: list[str], base: float = 1000.0) -> str:
@@ -96,6 +75,3 @@ def tree_struct_str(tree: Any, max_leaves: int = 40) -> str:
         lines.append(f"  ... ({len(flat) - max_leaves} more leaves)")
     return "\n".join(lines)
 
-
-def now_ms() -> float:
-    return time.perf_counter() * 1e3

@@ -343,73 +343,79 @@ def attention_decode(
     b, _, d = x.shape
     quant = k_scale is not None
 
-    q = jnp.einsum("bsd,dhk->bshk", x, qweight(params["wq"], x.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", x, qweight(params["wk"], x.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", x, qweight(params["wv"], x.dtype))
-    q = _head_constraint(q, mesh_info, 2)
-    k = _head_constraint(k, mesh_info, 2)
-    v = _head_constraint(v, mesh_info, 2)
-    cache_k = _head_constraint(cache_k, mesh_info, 1)
-    cache_v = _head_constraint(cache_v, mesh_info, 1)
-    if cfg.qk_norm:
-        q = rms_norm(q, params["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, params["k_norm"], cfg.norm_eps)
-    pos = jnp.broadcast_to(jnp.asarray(cur_len), (b,))[:, None]  # [B,1]
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    with jax.named_scope("attn_in"):
+        q = jnp.einsum("bsd,dhk->bshk", x, qweight(params["wq"], x.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", x, qweight(params["wk"], x.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", x, qweight(params["wv"], x.dtype))
+        q = _head_constraint(q, mesh_info, 2)
+        k = _head_constraint(k, mesh_info, 2)
+        v = _head_constraint(v, mesh_info, 2)
+        cache_k = _head_constraint(cache_k, mesh_info, 1)
+        cache_v = _head_constraint(cache_v, mesh_info, 1)
+        if cfg.qk_norm:
+            q = rms_norm(q, params["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, params["k_norm"], cfg.norm_eps)
+        pos = jnp.broadcast_to(jnp.asarray(cur_len), (b,))[:, None]  # [B,1]
+        q = apply_rope(q, pos, cfg.rope_theta)
+        k = apply_rope(k, pos, cfg.rope_theta)
 
-    if quant:
-        # per-(position, head)-row quantization of the freshly projected
-        # K/V — identity (payload, ones) when the cache stores f32
-        k, ks = quantize_rows(k, cache_k.dtype)
-        v, vs = quantize_rows(v, cache_v.dtype)
+        if quant:
+            # per-(position, head)-row quantization of the freshly projected
+            # K/V — identity (payload, ones) when the cache stores f32
+            k, ks = quantize_rows(k, cache_k.dtype)
+            v, vs = quantize_rows(v, cache_v.dtype)
 
     if block_tables is None:
-        # scatter the new k/v at cur_len on the head-major position axis
-        cache_k = _scatter_step(cache_k, k.transpose(0, 2, 1, 3), cur_len, 2)
-        cache_v = _scatter_step(cache_v, v.transpose(0, 2, 1, 3), cur_len, 2)
-        if quant:
-            k_scale = _scatter_step(k_scale, ks.transpose(0, 2, 1), cur_len, 2)
-            v_scale = _scatter_step(v_scale, vs.transpose(0, 2, 1), cur_len, 2)
+        with jax.named_scope("kv_write"):
+            # scatter the new k/v at cur_len on the head-major position axis
+            cache_k = _scatter_step(cache_k, k.transpose(0, 2, 1, 3), cur_len, 2)
+            cache_v = _scatter_step(cache_v, v.transpose(0, 2, 1, 3), cur_len, 2)
+            if quant:
+                k_scale = _scatter_step(k_scale, ks.transpose(0, 2, 1), cur_len, 2)
+                v_scale = _scatter_step(v_scale, vs.transpose(0, 2, 1), cur_len, 2)
 
         # grouped decode attention: never expands the cache to H heads
         # (materializing [B,S,H,hd] per layer is a groups× transient blowup
         # at 32k context); cache may be int8/f8 storage — the kernel widens
         # per-tile in-register, so no dequantized cache copy exists
-        o = _per_head(
-            mesh_info,
-            functools.partial(ops.decode_attention, window=cfg.sliding_window),
-            dict(q=q[:, 0], k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
-            dict(cur_len=cur_len),
-        )[:, None]  # [B,1,H,hd]
+        with jax.named_scope("attention"):
+            o = _per_head(
+                mesh_info,
+                functools.partial(ops.decode_attention, window=cfg.sliding_window),
+                dict(q=q[:, 0], k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
+                dict(cur_len=cur_len),
+            )[:, None]  # [B,1,H,hd]
     else:
         # paged: (slot, cur_len) -> (block, offset) through the sequence's
         # table row; an unallocated sentinel entry is out of pool range and
         # the write drops (inert/finished slots never corrupt a block that
         # was reassigned to another request)
-        bs = cache_k.shape[2]
-        p = pos[:, 0]
-        blk = block_tables[
-            jnp.arange(b), jnp.minimum(p // bs, block_tables.shape[1] - 1)
-        ]
-        cache_k = cache_k.at[blk, :, p % bs].set(
-            k[:, 0].astype(cache_k.dtype), mode="drop"
-        )
-        cache_v = cache_v.at[blk, :, p % bs].set(
-            v[:, 0].astype(cache_v.dtype), mode="drop"
-        )
-        if quant:
-            k_scale = k_scale.at[blk, :, p % bs].set(ks[:, 0], mode="drop")
-            v_scale = v_scale.at[blk, :, p % bs].set(vs[:, 0], mode="drop")
-        o = _per_head(
-            mesh_info,
-            functools.partial(
-                ops.paged_decode_attention, window=cfg.sliding_window
-            ),
-            dict(q=q[:, 0], k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
-            dict(cur_len=cur_len, block_tables=block_tables),
-        )[:, None]
-    out = jnp.einsum("bshk,hkd->bsd", o, qweight(params["wo"], o.dtype))
+        with jax.named_scope("kv_write"):
+            bs = cache_k.shape[2]
+            p = pos[:, 0]
+            blk = block_tables[
+                jnp.arange(b), jnp.minimum(p // bs, block_tables.shape[1] - 1)
+            ]
+            cache_k = cache_k.at[blk, :, p % bs].set(
+                k[:, 0].astype(cache_k.dtype), mode="drop"
+            )
+            cache_v = cache_v.at[blk, :, p % bs].set(
+                v[:, 0].astype(cache_v.dtype), mode="drop"
+            )
+            if quant:
+                k_scale = k_scale.at[blk, :, p % bs].set(ks[:, 0], mode="drop")
+                v_scale = v_scale.at[blk, :, p % bs].set(vs[:, 0], mode="drop")
+        with jax.named_scope("attention"):
+            o = _per_head(
+                mesh_info,
+                functools.partial(
+                    ops.paged_decode_attention, window=cfg.sliding_window
+                ),
+                dict(q=q[:, 0], k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
+                dict(cur_len=cur_len, block_tables=block_tables),
+            )[:, None]
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("bshk,hkd->bsd", o, qweight(params["wo"], o.dtype))
     if quant:
         return out, cache_k, cache_v, k_scale, v_scale
     return out, cache_k, cache_v
@@ -468,77 +474,83 @@ def attention_packed(
     step. Returns (out, new_k, new_v, new_k_scale, new_v_scale).
     """
     quant = k_scale is not None
-    q = jnp.einsum("td,dhk->thk", x, qweight(params["wq"], x.dtype))
-    k = jnp.einsum("td,dhk->thk", x, qweight(params["wk"], x.dtype))
-    v = jnp.einsum("td,dhk->thk", x, qweight(params["wv"], x.dtype))
-    q = _head_constraint(q, mesh_info, 1)
-    k = _head_constraint(k, mesh_info, 1)
-    v = _head_constraint(v, mesh_info, 1)
-    cache_k = _head_constraint(cache_k, mesh_info, 1)
-    cache_v = _head_constraint(cache_v, mesh_info, 1)
-    if cfg.qk_norm:
-        q = rms_norm(q, params["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, params["k_norm"], cfg.norm_eps)
-    pos = jnp.asarray(tok_pos, jnp.int32)
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    with jax.named_scope("attn_in"):
+        q = jnp.einsum("td,dhk->thk", x, qweight(params["wq"], x.dtype))
+        k = jnp.einsum("td,dhk->thk", x, qweight(params["wk"], x.dtype))
+        v = jnp.einsum("td,dhk->thk", x, qweight(params["wv"], x.dtype))
+        q = _head_constraint(q, mesh_info, 1)
+        k = _head_constraint(k, mesh_info, 1)
+        v = _head_constraint(v, mesh_info, 1)
+        cache_k = _head_constraint(cache_k, mesh_info, 1)
+        cache_v = _head_constraint(cache_v, mesh_info, 1)
+        if cfg.qk_norm:
+            q = rms_norm(q, params["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, params["k_norm"], cfg.norm_eps)
+        pos = jnp.asarray(tok_pos, jnp.int32)
+        q = apply_rope(q, pos, cfg.rope_theta)
+        k = apply_rope(k, pos, cfg.rope_theta)
 
-    glob_slot = tok_slot if pack_slots is None else pack_slots[tok_slot]
-    if quant:
-        k, ks = quantize_rows(k, cache_k.dtype)  # [T,KV,hd] -> scale [T,KV]
-        v, vs = quantize_rows(v, cache_v.dtype)
+        glob_slot = tok_slot if pack_slots is None else pack_slots[tok_slot]
+        if quant:
+            k, ks = quantize_rows(k, cache_k.dtype)  # [T,KV,hd] -> scale [T,KV]
+            v, vs = quantize_rows(v, cache_v.dtype)
     if block_tables is None:
         # one fused scatter for the whole pack replaces the per-admission
         # full-cache insert: O(T) rows written, never a cache-sized copy
-        cache_k = cache_k.at[glob_slot, :, pos].set(k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[glob_slot, :, pos].set(v.astype(cache_v.dtype), mode="drop")
-        if quant:
-            k_scale = k_scale.at[glob_slot, :, pos].set(ks, mode="drop")
-            v_scale = v_scale.at[glob_slot, :, pos].set(vs, mode="drop")
+        with jax.named_scope("kv_write"):
+            cache_k = cache_k.at[glob_slot, :, pos].set(k.astype(cache_k.dtype), mode="drop")
+            cache_v = cache_v.at[glob_slot, :, pos].set(v.astype(cache_v.dtype), mode="drop")
+            if quant:
+                k_scale = k_scale.at[glob_slot, :, pos].set(ks, mode="drop")
+                v_scale = v_scale.at[glob_slot, :, pos].set(vs, mode="drop")
 
-        if pack_slots is None:
-            att_k, att_v = cache_k, cache_v
-            att_ks, att_vs = k_scale, v_scale
-        else:  # P-row sub-cache view: attention work scales with the pack
-            att_k, att_v = cache_k[pack_slots], cache_v[pack_slots]
-            att_ks = None if k_scale is None else k_scale[pack_slots]
-            att_vs = None if v_scale is None else v_scale[pack_slots]
-        o = _per_head(
-            mesh_info,
-            functools.partial(ops.ragged_attention, window=cfg.sliding_window),
-            dict(q=q, k=att_k, v=att_v, k_scale=att_ks, v_scale=att_vs),
-            dict(tok_slot=tok_slot, tok_pos=pos, valid=valid),
-        )  # [T, H, hd]
+        with jax.named_scope("attention"):
+            if pack_slots is None:
+                att_k, att_v = cache_k, cache_v
+                att_ks, att_vs = k_scale, v_scale
+            else:  # P-row sub-cache view: attention work scales with the pack
+                att_k, att_v = cache_k[pack_slots], cache_v[pack_slots]
+                att_ks = None if k_scale is None else k_scale[pack_slots]
+                att_vs = None if v_scale is None else v_scale[pack_slots]
+            o = _per_head(
+                mesh_info,
+                functools.partial(ops.ragged_attention, window=cfg.sliding_window),
+                dict(q=q, k=att_k, v=att_v, k_scale=att_ks, v_scale=att_vs),
+                dict(tok_slot=tok_slot, tok_pos=pos, valid=valid),
+            )  # [T, H, hd]
     else:
         # paged pool: same fused scatter through the (block, offset)
         # indirection. Positions past the table (bucket padding) pick the
         # out-of-range sentinel explicitly — clamping the table index and
         # letting a real block id through would corrupt offset 0 of a live
         # block; mode="drop" needs the OOB id to survive to the scatter
-        bs = cache_k.shape[2]
-        maxb = block_tables.shape[1]
-        nb = cache_k.shape[0]
-        bidx = jnp.minimum(pos // bs, maxb - 1)
-        blk = jnp.where(pos < maxb * bs, block_tables[glob_slot, bidx], nb)
-        off = pos % bs
-        cache_k = cache_k.at[blk, :, off].set(k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[blk, :, off].set(v.astype(cache_v.dtype), mode="drop")
-        if quant:
-            k_scale = k_scale.at[blk, :, off].set(ks, mode="drop")
-            v_scale = v_scale.at[blk, :, off].set(vs, mode="drop")
+        with jax.named_scope("kv_write"):
+            bs = cache_k.shape[2]
+            maxb = block_tables.shape[1]
+            nb = cache_k.shape[0]
+            bidx = jnp.minimum(pos // bs, maxb - 1)
+            blk = jnp.where(pos < maxb * bs, block_tables[glob_slot, bidx], nb)
+            off = pos % bs
+            cache_k = cache_k.at[blk, :, off].set(k.astype(cache_k.dtype), mode="drop")
+            cache_v = cache_v.at[blk, :, off].set(v.astype(cache_v.dtype), mode="drop")
+            if quant:
+                k_scale = k_scale.at[blk, :, off].set(ks, mode="drop")
+                v_scale = v_scale.at[blk, :, off].set(vs, mode="drop")
 
-        att_btab = (
-            block_tables if pack_slots is None else block_tables[pack_slots]
-        )
-        o = _per_head(
-            mesh_info,
-            functools.partial(
-                ops.paged_ragged_attention, window=cfg.sliding_window
-            ),
-            dict(q=q, k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
-            dict(tok_seq=tok_slot, tok_pos=pos, block_tables=att_btab, valid=valid),
-        )  # [T, H, hd]
-    out = jnp.einsum("thk,hkd->td", o, qweight(params["wo"], o.dtype))
+        with jax.named_scope("attention"):
+            att_btab = (
+                block_tables if pack_slots is None else block_tables[pack_slots]
+            )
+            o = _per_head(
+                mesh_info,
+                functools.partial(
+                    ops.paged_ragged_attention, window=cfg.sliding_window
+                ),
+                dict(q=q, k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
+                dict(tok_seq=tok_slot, tok_pos=pos, block_tables=att_btab, valid=valid),
+            )  # [T, H, hd]
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("thk,hkd->td", o, qweight(params["wo"], o.dtype))
     if quant:
         return out, cache_k, cache_v, k_scale, v_scale
     return out, cache_k, cache_v

@@ -17,7 +17,12 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.kernels import ops
-from repro.models.attention import NEG_INF, chunked_attention, dense_attention
+from repro.models.attention import (
+    NEG_INF,
+    _scatter_step,
+    chunked_attention,
+    dense_attention,
+)
 from repro.models.layers import Params, apply_rope, dense_init, rms_norm
 
 
@@ -133,39 +138,41 @@ def mla_packed(
     only those P gathered latent rows. Returns (out [T, d], new_ckv,
     new_krope)."""
     m = cfg.mla
-    pos = jnp.asarray(tok_pos, jnp.int32)
+    with jax.named_scope("attn_in"):
+        pos = jnp.asarray(tok_pos, jnp.int32)
+        q = _project_q(params, cfg, x[None])[0]  # [T,H,nope+rope]
+        q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
+        q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
 
-    q = _project_q(params, cfg, x[None])[0]  # [T,H,nope+rope]
-    q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+        ckv = jnp.einsum("td,dr->tr", x, params["wkv_a"])  # [T,kv_lora+rope]
+        c_kv, k_rope = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
+        c_kv = rms_norm(c_kv, params["kv_norm"], cfg.norm_eps)
+        k_rope = apply_rope(k_rope[:, None, :], pos, cfg.rope_theta)[:, 0, :]
+        # absorb W_uk into q (q_eff [T,H,kv_lora]) to attend in latent space
+        w_uk = params["wkv_b"][..., : m.nope_head_dim]  # [r,H,nope]
+        q_eff = jnp.einsum("thk,rhk->thr", q_nope, w_uk)
 
-    ckv = jnp.einsum("td,dr->tr", x, params["wkv_a"])  # [T,kv_lora+rope]
-    c_kv, k_rope = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
-    c_kv = rms_norm(c_kv, params["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, None, :], pos, cfg.rope_theta)[:, 0, :]
-
-    glob_slot = tok_slot if pack_slots is None else pack_slots[tok_slot]
-    cache_ckv = cache_ckv.at[glob_slot, pos].set(
-        c_kv.astype(cache_ckv.dtype), mode="drop"
-    )
-    cache_krope = cache_krope.at[glob_slot, pos].set(
-        k_rope.astype(cache_krope.dtype), mode="drop"
-    )
-    if pack_slots is None:
-        att_ckv, att_krope = cache_ckv, cache_krope
-    else:  # P-row sub-cache view: attention work scales with the pack
-        att_ckv, att_krope = cache_ckv[pack_slots], cache_krope[pack_slots]
-
-    # absorb W_uk into q (q_eff [T,H,kv_lora]) and attend in latent space
-    w_uk = params["wkv_b"][..., : m.nope_head_dim]  # [r,H,nope]
-    q_eff = jnp.einsum("thk,rhk->thr", q_nope, w_uk)
-    lat = ops.mla_ragged_attention(
-        q_eff, q_rope, att_ckv, att_krope, tok_slot, pos,
-        scale=(m.nope_head_dim + m.rope_head_dim) ** -0.5, valid=valid,
-    )  # [T,H,r]
-    w_uv = params["wkv_b"][..., m.nope_head_dim :]  # [r,H,v]
-    o = jnp.einsum("thr,rhv->thv", lat.astype(x.dtype), w_uv)
-    out = jnp.einsum("thv,hvd->td", o, params["wo"])
+    with jax.named_scope("kv_write"):
+        glob_slot = tok_slot if pack_slots is None else pack_slots[tok_slot]
+        cache_ckv = cache_ckv.at[glob_slot, pos].set(
+            c_kv.astype(cache_ckv.dtype), mode="drop"
+        )
+        cache_krope = cache_krope.at[glob_slot, pos].set(
+            k_rope.astype(cache_krope.dtype), mode="drop"
+        )
+    with jax.named_scope("attention"):
+        if pack_slots is None:
+            att_ckv, att_krope = cache_ckv, cache_krope
+        else:  # P-row sub-cache view: attention work scales with the pack
+            att_ckv, att_krope = cache_ckv[pack_slots], cache_krope[pack_slots]
+        lat = ops.mla_ragged_attention(
+            q_eff, q_rope, att_ckv, att_krope, tok_slot, pos,
+            scale=(m.nope_head_dim + m.rope_head_dim) ** -0.5, valid=valid,
+        )  # [T,H,r]
+    with jax.named_scope("attn_out"):
+        w_uv = params["wkv_b"][..., m.nope_head_dim :]  # [r,H,v]
+        o = jnp.einsum("thr,rhv->thv", lat.astype(x.dtype), w_uv)
+        out = jnp.einsum("thv,hvd->td", o, params["wo"])
     return out, cache_ckv, cache_krope
 
 
@@ -182,43 +189,47 @@ def mla_decode(
     x: [B,1,d]; cache_ckv: [B,S,kv_lora]; cache_krope: [B,S,rope_dim].
     """
     m = cfg.mla
-    H = cfg.n_heads
     b = x.shape[0]
     s_max = cache_ckv.shape[1]
-    pos = jnp.broadcast_to(jnp.asarray(cur_len), (b,))[:, None]  # [B,1]
+    with jax.named_scope("attn_in"):
+        pos = jnp.broadcast_to(jnp.asarray(cur_len), (b,))[:, None]  # [B,1]
+        q = _project_q(params, cfg, x)  # [B,1,H,nope+rope]
+        q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
+        q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
 
-    q = _project_q(params, cfg, x)  # [B,1,H,nope+rope]
-    q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+        ckv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
+        c_kv, k_rope = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
+        c_kv = rms_norm(c_kv, params["kv_norm"], cfg.norm_eps)
+        k_rope = apply_rope(k_rope[:, :, None, :], pos, cfg.rope_theta)[:, :, 0, :]
+        # absorb W_uk into q: q_eff [B,1,H,kv_lora]
+        w_uk = params["wkv_b"][..., : m.nope_head_dim]  # [r,H,nope]
+        q_eff = jnp.einsum("bshk,rhk->bshr", q_nope, w_uk)
 
-    ckv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
-    c_kv, k_rope = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
-    c_kv = rms_norm(c_kv, params["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], pos, cfg.rope_theta)[:, :, 0, :]
+    with jax.named_scope("kv_write"):
+        cache_ckv = _scatter_step(cache_ckv, c_kv, cur_len)
+        cache_krope = _scatter_step(cache_krope, k_rope, cur_len)
 
-    from repro.models.attention import _scatter_step
-
-    cache_ckv = _scatter_step(cache_ckv, c_kv, cur_len)
-    cache_krope = _scatter_step(cache_krope, k_rope, cur_len)
-
-    # absorb W_uk into q: q_eff [B,1,H,kv_lora]
-    w_uk = params["wkv_b"][..., : m.nope_head_dim]  # [r,H,nope]
-    q_eff = jnp.einsum("bshk,rhk->bshr", q_nope, w_uk)
-    scores = (
-        jnp.einsum("bshr,btr->bhst", q_eff.astype(jnp.float32), cache_ckv.astype(jnp.float32))
-        + jnp.einsum(
-            "bshk,btk->bhst", q_rope.astype(jnp.float32), cache_krope.astype(jnp.float32)
+    # the latent MQA core: q_eff·cache scores through the latent readout
+    with jax.named_scope("attention"):
+        scores = (
+            jnp.einsum("bshr,btr->bhst", q_eff.astype(jnp.float32),
+                       cache_ckv.astype(jnp.float32))
+            + jnp.einsum(
+                "bshk,btk->bhst", q_rope.astype(jnp.float32),
+                cache_krope.astype(jnp.float32)
+            )
+        ) * ((m.nope_head_dim + m.rope_head_dim) ** -0.5)
+        valid = (jnp.arange(s_max)[None, :]
+                 <= jnp.broadcast_to(jnp.asarray(cur_len), (b,))[:, None])
+        scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        lat = jnp.einsum(
+            "bhst,btr->bshr", probs.astype(x.dtype), cache_ckv.astype(x.dtype)
         )
-    ) * ((m.nope_head_dim + m.rope_head_dim) ** -0.5)
-    valid = jnp.arange(s_max)[None, :] <= jnp.broadcast_to(jnp.asarray(cur_len), (b,))[:, None]
-    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
 
-    # attend in latent space, then decompress through W_uv (absorbed output)
-    lat = jnp.einsum(
-        "bhst,btr->bshr", probs.astype(x.dtype), cache_ckv.astype(x.dtype)
-    )
-    w_uv = params["wkv_b"][..., m.nope_head_dim :]  # [r,H,v]
-    o = jnp.einsum("bshr,rhv->bshv", lat, w_uv)
-    out = jnp.einsum("bshv,hvd->bsd", o, params["wo"])
+    # decompress through W_uv (absorbed output)
+    with jax.named_scope("attn_out"):
+        w_uv = params["wkv_b"][..., m.nope_head_dim :]  # [r,H,v]
+        o = jnp.einsum("bshr,rhv->bshv", lat, w_uv)
+        out = jnp.einsum("bshv,hvd->bsd", o, params["wo"])
     return out, cache_ckv, cache_krope

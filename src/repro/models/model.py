@@ -585,7 +585,8 @@ class LM:
     ) -> tuple[jax.Array, Params]:
         """One layer's decode. cache_l leaves have NO leading L axis here."""
         cfg = self.cfg
-        h = rms_norm(x, blk["norm1"], cfg.norm_eps)
+        with jax.named_scope("attn_in"):
+            h = rms_norm(x, blk["norm1"], cfg.norm_eps)
         if cfg.mla is not None:
             a, ckv, krope = mla_mod.mla_decode(
                 blk["attn"], cfg, h, cache_l["ckv"], cache_l["krope"], cur_len
@@ -604,13 +605,15 @@ class LM:
                 mesh_info=self.mesh_info, block_tables=block_tables,
             )
             new_cache = {"k": ck, "v": cv}
-        x = x + a
-        h = rms_norm(x, blk["norm2"], cfg.norm_eps)
-        if "moe" in blk:
-            out, _ = moe_mod.moe_apply(blk["moe"], cfg, h, mesh_info=self.mesh_info)
-            x = x + out
-        else:
-            x = x + mlp_apply(blk["mlp"], h)
+        with jax.named_scope("attn_out"):
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, blk["norm2"], cfg.norm_eps)
+            if "moe" in blk:
+                out, _ = moe_mod.moe_apply(blk["moe"], cfg, h, mesh_info=self.mesh_info)
+                x = x + out
+            else:
+                x = x + mlp_apply(blk["mlp"], h)
         return x, new_cache
 
     def _mamba_decode(self, blk, x, cache_l):
@@ -707,7 +710,8 @@ class LM:
     ) -> tuple[jax.Array, Params]:
         """One layer over a packed [T] token batch. cache_l has no L axis."""
         cfg = self.cfg
-        h = rms_norm(x, blk["norm1"], cfg.norm_eps)
+        with jax.named_scope("attn_in"):
+            h = rms_norm(x, blk["norm1"], cfg.norm_eps)
         if cfg.mla is not None:  # latent-space packed step (never paged)
             a, nckv, nkrope = mla_mod.mla_packed(
                 blk["attn"], cfg, h, cache_l["ckv"], cache_l["krope"],
@@ -729,15 +733,17 @@ class LM:
                 mesh_info=self.mesh_info, block_tables=block_tables,
             )
             new_cache = {"k": ck, "v": cv}
-        x = x + a
-        h = rms_norm(x, blk["norm2"], cfg.norm_eps)
-        if "moe" in blk:
-            out, _ = moe_mod.moe_apply(
-                blk["moe"], cfg, h[None], mesh_info=self.mesh_info
-            )
-            x = x + out[0]
-        else:
-            x = x + mlp_apply(blk["mlp"], h)
+        with jax.named_scope("attn_out"):
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, blk["norm2"], cfg.norm_eps)
+            if "moe" in blk:
+                out, _ = moe_mod.moe_apply(
+                    blk["moe"], cfg, h[None], mesh_info=self.mesh_info
+                )
+                x = x + out[0]
+            else:
+                x = x + mlp_apply(blk["mlp"], h)
         return x, new_cache
 
     def packed_step(
@@ -833,10 +839,11 @@ class LM:
             blocks = params["blocks"] if cfg.family == "dense" else params["moe_blocks"]
             x, new_cache = jax.lax.scan(body, x, (blocks, cache))
 
-        if out_rows is not None:
-            x = x[out_rows]
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(params["embed"], x)
+        with jax.named_scope("head"):
+            if out_rows is not None:
+                x = x[out_rows]
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            logits = unembed(params["embed"], x)
         return logits, new_cache
 
     # ------------------------------------------------------------ decode step
@@ -938,8 +945,9 @@ class LM:
         else:
             raise ValueError(cfg.family)
 
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(params["embed"], x)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            logits = unembed(params["embed"], x)
         return logits, new_cache
 
 

@@ -409,6 +409,14 @@ def _warn_legacy_tier(tag: str) -> None:
     )
 
 
+def _span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span ``engine.<name>`` in the profiler's trace, on the clock
+    of the device's operations. Spans mark one scheduling step's phases,
+    never a request or a token; with no profiler active one costs about a
+    microsecond."""
+    return jax.profiler.TraceAnnotation("engine." + name)
+
+
 def _bucket_tokens(t: int) -> int:
     for b in _T_BUCKETS:
         if t <= b:
@@ -794,11 +802,12 @@ class ServeEngine:
                     new_cache, cache,
                 )
             cache = new_cache
-            new = fused_sample(
-                logits[:, 0], spf[0], spi[0], spf[1], spi[1], cl,
-                btok, bval, smode=smode,
-            )
-            tok = jnp.where(active, new, tok)
+            with jax.named_scope("sample"):
+                new = fused_sample(
+                    logits[:, 0], spf[0], spi[0], spf[1], spi[1], cl,
+                    btok, bval, smode=smode,
+                )
+                tok = jnp.where(active, new, tok)
             return (tok, cl + adv, cache), tok
 
         (last_tok, cur_len, cache), toks = jax.lax.scan(
@@ -825,11 +834,12 @@ class ServeEngine:
             logits, cache = self.model.decode_step(
                 params, cache, {"tokens": tok[:, None]}, cl, block_tables=btab
             )
-            new = fused_sample(
-                logits[:, 0], spf[0], spi[0], spf[1], spi[1], cl,
-                btok, bval, smode=smode,
-            )
-            tok = jnp.where(active, new, tok)
+            with jax.named_scope("sample"):
+                new = fused_sample(
+                    logits[:, 0], spf[0], spi[0], spf[1], spi[1], cl,
+                    btok, bval, smode=smode,
+                )
+                tok = jnp.where(active, new, tok)
             return (tok, cl + adv, cache), tok
 
         (last_tok, cur_len, cache), toks = jax.lax.scan(
@@ -853,11 +863,12 @@ class ServeEngine:
             params, cache, desc[0], desc[1], desc[2],
             out_rows=sample_idx, pack_slots=pack_slots, block_tables=btab,
         )
-        sampled = fused_sample(
-            logits, spf[0], spi[0], spf[1], spi[1], new_len - 1,
-            btok, bval, smode=smode,
-        )
-        last_tok = jnp.where(sample_mask, sampled, last_tok)
+        with jax.named_scope("sample"):
+            sampled = fused_sample(
+                logits, spf[0], spi[0], spf[1], spi[1], new_len - 1,
+                btok, bval, smode=smode,
+            )
+            last_tok = jnp.where(sample_mask, sampled, last_tok)
         return sampled, last_tok, new_len, cache
 
     def _packed_fn(self, params, cache, last_tok, desc, meta, spf, spi,
@@ -886,11 +897,12 @@ class ServeEngine:
             params, cache, desc[0], desc[1], desc[2],
             out_rows=sample_idx, pack_slots=pack_slots, max_len=self.max_len,
         )
-        sampled = fused_sample(
-            logits, spf[0], spi[0], spf[1], spi[1], new_len - 1,
-            btok, bval, smode=smode,
-        )
-        last_tok = jnp.where(sample_mask, sampled, last_tok)
+        with jax.named_scope("sample"):
+            sampled = fused_sample(
+                logits, spf[0], spi[0], spf[1], spi[1], new_len - 1,
+                btok, bval, smode=smode,
+            )
+            last_tok = jnp.where(sample_mask, sampled, last_tok)
         return sampled, last_tok, new_len, cache
 
     def _spec_fn(self, params, cache, last_tok, cur_len, pack, spf,
@@ -926,10 +938,11 @@ class ServeEngine:
             params, cache, desc[0], desc[1], desc[2]
         )
         drafts = desc[0][: b * w].reshape(b, w)[:, 1:]
-        targets, n_acc, commit = spec_verify(
-            logits[: b * w], drafts, depth, act, spf[0], spi[0], spf[1],
-            spi[1], cl, btok, bval, smode=smode,
-        )
+        with jax.named_scope("sample"):
+            targets, n_acc, commit = spec_verify(
+                logits[: b * w], drafts, depth, act, spf[0], spi[0], spf[1],
+                spi[1], cl, btok, bval, smode=smode,
+            )
         last_tok = jnp.where(active, targets[jnp.arange(b), n_acc], last_tok)
         cur_len = jnp.where(active, cl + commit, cur_len)
         return targets, commit, last_tok, cur_len, cache
@@ -951,10 +964,11 @@ class ServeEngine:
             params, cache, desc[0], desc[1], desc[2], block_tables=btab
         )
         drafts = desc[0][: b * w].reshape(b, w)[:, 1:]
-        targets, n_acc, commit = spec_verify(
-            logits[: b * w], drafts, depth, act, spf[0], spi[0], spf[1],
-            spi[1], cl, btok, bval, smode=smode,
-        )
+        with jax.named_scope("sample"):
+            targets, n_acc, commit = spec_verify(
+                logits[: b * w], drafts, depth, act, spf[0], spi[0], spf[1],
+                spi[1], cl, btok, bval, smode=smode,
+            )
         last_tok = jnp.where(active, targets[jnp.arange(b), n_acc], last_tok)
         cur_len = jnp.where(active, cl + commit, cur_len)
         return targets, commit, last_tok, cur_len, cache
@@ -973,10 +987,11 @@ class ServeEngine:
         )
         cache = self._insert_fn(cache, one_cache, slot)
         row = logits[0, last_pos]  # [V]
-        tok = fused_sample(
-            row[None], sampf[:1], sampi[:1], sampf[1:], sampi[1:],
-            last_pos[None], btok, bval, smode=smode,
-        )[0]
+        with jax.named_scope("sample"):
+            tok = fused_sample(
+                row[None], sampf[:1], sampi[:1], sampf[1:], sampi[1:],
+                last_pos[None], btok, bval, smode=smode,
+            )[0]
         last_tok = last_tok.at[slot].set(tok)
         cur_len = cur_len.at[slot].set(last_pos + 1)
         return tok, last_tok, cur_len, cache
@@ -1079,28 +1094,30 @@ class ServeEngine:
         that counted its dispatch, so a discard refund always lands on the
         counter that was incremented — even when a chunk dispatched under
         step()-driven streaming is harvested inside a later run()."""
-        kind, tok_dev, items, stats = entry
-        toks = np.asarray(tok_dev)
-        now = time.perf_counter()
+        with _span("harvest"):
+            kind, tok_dev, items, stats = entry
+            with _span("wait"):
+                toks = np.asarray(tok_dev)
+            now = time.perf_counter()
 
-        if kind == "admit":  # fused admission: one scalar first token
-            slot, req = items
-            self._credit(req, int(toks), now, stats, first=True)
-            self._stamp(req, now)
-        elif kind == "packed":  # [B] one sample per flagged slot
-            for slot, req, is_first in items:
-                self._credit(req, int(toks[slot]), now, stats, first=is_first)
+            if kind == "admit":  # fused admission: one scalar first token
+                slot, req = items
+                self._credit(req, int(toks), now, stats, first=True)
                 self._stamp(req, now)
-        else:  # decode chunk: [n_steps, B]
-            for slot, req in items:
-                if not req._stop and len(req.generated) + len(toks) <= req.n_generated:
-                    # no stop set and no overrun: bulk-extend (the all-greedy
-                    # steady state takes this path for every chunk)
-                    req.generated.extend(int(t) for t in toks[:, slot])
-                else:
-                    for t in toks[:, slot]:
-                        self._credit(req, int(t), now, stats)
-                self._stamp(req, now)
+            elif kind == "packed":  # [B] one sample per flagged slot
+                for slot, req, is_first in items:
+                    self._credit(req, int(toks[slot]), now, stats, first=is_first)
+                    self._stamp(req, now)
+            else:  # decode chunk: [n_steps, B]
+                for slot, req in items:
+                    if not req._stop and len(req.generated) + len(toks) <= req.n_generated:
+                        # no stop set and no overrun: bulk-extend (the all-greedy
+                        # steady state takes this path for every chunk)
+                        req.generated.extend(int(t) for t in toks[:, slot])
+                    else:
+                        for t in toks[:, slot]:
+                            self._credit(req, int(t), now, stats)
+                    self._stamp(req, now)
 
     def _drain_pending(self) -> None:
         while self._pending:
@@ -1641,112 +1658,113 @@ class ServeEngine:
         loop rides a fused decode chunk alongside every pack, so admission
         work and decode progress share each loop iteration instead of
         queueing behind each other."""
-        entries: list[tuple[int, int, int]] = []  # (token, LOCAL slot, pos)
-        sample_idx = np.zeros(self.B, np.int32)
-        sample_mask = np.zeros(self.B, bool)
-        # the pack spans at most _pack_width admitting slots: attention
-        # work (and the compile count — one variant) scales with the pack,
-        # not the slot pool; later admissions simply join the next tick's
-        # pack. SSM packs are width 1 (one contiguous stream per chunk).
-        pack_slots = np.zeros(self._pack_width, np.int32)
-        budget = self.prefill_budget
-        completed: list[int] = []
-        for local, i in enumerate(self._prefilling[: self._pack_width]):
-            if budget <= 0:
-                break
-            pack_slots[local] = i
-            req = self.slot_req[i]
-            fed = int(self.slot_fed[i])
-            n = min(budget, len(req.prompt) - fed)
-            budget -= n
-            for j in range(n):
-                entries.append((int(req.prompt[fed + j]), local, fed + j))
-            self.slot_fed[i] = fed + n
-            self.slot_len[i] = fed + n
-            if fed + n == len(req.prompt):
-                sample_idx[i] = len(entries) - 1  # the final prompt token
-                sample_mask[i] = True
-                completed.append(i)
-                self._prefilling.remove(i)
-                self._dirty = True  # becomes an active decoder
-        tb = _bucket_tokens(len(entries))
-        if tb not in self._packed_shapes:
-            self._packed_shapes.add(tb)
-            stats.prefill_compiles += 1
-        # combined uploads, built fresh every tick (CPU device_put can
-        # be zero-copy, so jax must never see a buffer the host mutates
-        # later). Padding tokens scatter out of bounds (dropped) and attend
-        # slot 0 with an all-valid mask; their output rows are never sampled
-        desc = np.zeros((3, tb), np.int32)
-        desc[2] = self.max_len
-        for t, (tok, sl, pos) in enumerate(entries):
-            desc[0, t] = tok
-            desc[1, t] = sl
-            desc[2, t] = pos
-        meta = np.concatenate(
-            [self.slot_len, sample_idx, sample_mask.astype(np.int32), pack_slots]
-        )
-        # only the slots SAMPLED by this pack pick the compiled variant —
-        # mid-prefill neighbours don't widen the dispatch; an all-greedy
-        # pack reuses the cached zero sampler rows (dead in smode 0)
-        smode = max(
-            (self.slot_req[i]._smode for i in completed), default=SMODE_GREEDY
-        )
-        if smode:
-            # refresh the RESIDENT rows (once): the fused decode chunk in
-            # this same iteration — and _flush_events — reuse them instead
-            # of re-building and re-uploading identical arrays
-            if not self._sp_fresh:
-                self._put_sp(*self._sp_rows())
-            spf, spi, btok, bval = self._spf, self._spi, self._btok, self._bval
-        else:
-            spf, spi, btok, bval = self._sp0
-
-        if self.paged:
-            toks, self._last_tok, self._cur_len, self.cache = (
-                self._packed_paged(
-                    self.params, self.cache, self._flush_btab(),
-                    self._last_tok,
-                    self.backend.put_host(desc), self.backend.put_host(meta),
-                    spf, spi, btok, bval,
-                    smode=smode,
-                )
+        with _span("pack"):
+            entries: list[tuple[int, int, int]] = []  # (token, LOCAL slot, pos)
+            sample_idx = np.zeros(self.B, np.int32)
+            sample_mask = np.zeros(self.B, bool)
+            # the pack spans at most _pack_width admitting slots: attention
+            # work (and the compile count — one variant) scales with the pack,
+            # not the slot pool; later admissions simply join the next tick's
+            # pack. SSM packs are width 1 (one contiguous stream per chunk).
+            pack_slots = np.zeros(self._pack_width, np.int32)
+            budget = self.prefill_budget
+            completed: list[int] = []
+            for local, i in enumerate(self._prefilling[: self._pack_width]):
+                if budget <= 0:
+                    break
+                pack_slots[local] = i
+                req = self.slot_req[i]
+                fed = int(self.slot_fed[i])
+                n = min(budget, len(req.prompt) - fed)
+                budget -= n
+                for j in range(n):
+                    entries.append((int(req.prompt[fed + j]), local, fed + j))
+                self.slot_fed[i] = fed + n
+                self.slot_len[i] = fed + n
+                if fed + n == len(req.prompt):
+                    sample_idx[i] = len(entries) - 1  # the final prompt token
+                    sample_mask[i] = True
+                    completed.append(i)
+                    self._prefilling.remove(i)
+                    self._dirty = True  # becomes an active decoder
+            tb = _bucket_tokens(len(entries))
+            if tb not in self._packed_shapes:
+                self._packed_shapes.add(tb)
+                stats.prefill_compiles += 1
+            # combined uploads, built fresh every tick (CPU device_put can
+            # be zero-copy, so jax must never see a buffer the host mutates
+            # later). Padding tokens scatter out of bounds (dropped) and attend
+            # slot 0 with an all-valid mask; their output rows are never sampled
+            desc = np.zeros((3, tb), np.int32)
+            desc[2] = self.max_len
+            for t, (tok, sl, pos) in enumerate(entries):
+                desc[0, t] = tok
+                desc[1, t] = sl
+                desc[2, t] = pos
+            meta = np.concatenate(
+                [self.slot_len, sample_idx, sample_mask.astype(np.int32), pack_slots]
             )
-        else:
-            toks, self._last_tok, self._cur_len, self.cache = (
-                self._packed(
-                    self.params, self.cache, self._last_tok,
-                    self.backend.put_host(desc), self.backend.put_host(meta),
-                    spf, spi, btok, bval,
-                    smode=smode,
-                )
+            # only the slots SAMPLED by this pack pick the compiled variant —
+            # mid-prefill neighbours don't widen the dispatch; an all-greedy
+            # pack reuses the cached zero sampler rows (dead in smode 0)
+            smode = max(
+                (self.slot_req[i]._smode for i in completed), default=SMODE_GREEDY
             )
-        stats.ticks += 1
+            if smode:
+                # refresh the RESIDENT rows (once): the fused decode chunk in
+                # this same iteration — and _flush_events — reuse them instead
+                # of re-building and re-uploading identical arrays
+                if not self._sp_fresh:
+                    self._put_sp(*self._sp_rows())
+                spf, spi, btok, bval = self._spf, self._spi, self._btok, self._bval
+            else:
+                spf, spi, btok, bval = self._sp0
 
-        if completed:
-            if self.paged and self.prefix is not None:
-                # the prompt's K/V now exists in this slot's blocks (the
-                # dispatch above is ordered before any future reader) —
-                # register its full prompt blocks so later requests skip
-                # them. Insert BEFORE any instant finish below: the tree
-                # takes its own references, so the blocks outlive the
-                # request until evicted.
-                for i in completed:
-                    self.prefix.insert(
-                        self.slot_req[i].prompt, self._slot_blocks[i]
+            if self.paged:
+                toks, self._last_tok, self._cur_len, self.cache = (
+                    self._packed_paged(
+                        self.params, self.cache, self._flush_btab(),
+                        self._last_tok,
+                        self.backend.put_host(desc), self.backend.put_host(meta),
+                        spf, spi, btok, bval,
+                        smode=smode,
                     )
-            items = []
-            for i in completed:
-                req = self.slot_req[i]
-                req.n_generated += 1  # the request's first token (not counted
-                items.append((i, req, True))  # in total_tokens, like legacy)
-            pending.append(("packed", toks, items, stats))
-            for i in completed:
-                req = self.slot_req[i]
-                # no capacity check: admission guarantees prompt < max_len,
-                # so one decode write at position len(prompt) always fits
-                if req.n_generated >= req.params.max_new:
-                    self._finish(req, i, stats)
+                )
+            else:
+                toks, self._last_tok, self._cur_len, self.cache = (
+                    self._packed(
+                        self.params, self.cache, self._last_tok,
+                        self.backend.put_host(desc), self.backend.put_host(meta),
+                        spf, spi, btok, bval,
+                        smode=smode,
+                    )
+                )
+            stats.ticks += 1
+
+            if completed:
+                if self.paged and self.prefix is not None:
+                    # the prompt's K/V now exists in this slot's blocks (the
+                    # dispatch above is ordered before any future reader) —
+                    # register its full prompt blocks so later requests skip
+                    # them. Insert BEFORE any instant finish below: the tree
+                    # takes its own references, so the blocks outlive the
+                    # request until evicted.
+                    for i in completed:
+                        self.prefix.insert(
+                            self.slot_req[i].prompt, self._slot_blocks[i]
+                        )
+                items = []
+                for i in completed:
+                    req = self.slot_req[i]
+                    req.n_generated += 1  # the request's first token (not counted
+                    items.append((i, req, True))  # in total_tokens, like legacy)
+                pending.append(("packed", toks, items, stats))
+                for i in completed:
+                    req = self.slot_req[i]
+                    # no capacity check: admission guarantees prompt < max_len,
+                    # so one decode write at position len(prompt) always fits
+                    if req.n_generated >= req.params.max_new:
+                        self._finish(req, i, stats)
 
     def _chunk_tick(self, stats: ServeStats, pending: deque, active: list[int]) -> None:
         """One fused multi-step decode chunk: as long as no active slot can
@@ -1755,47 +1773,48 @@ class ServeEngine:
         compile). Stop tokens cannot participate here — the host never
         waits on values — so a stop-terminated slot overruns by at most
         one chunk, discarded at credit time."""
-        rem = min(
-            min(
-                self.slot_req[i].params.max_new - self.slot_req[i].n_generated,
-                self.max_len - 1 - int(self.slot_len[i]),
-            )
-            for i in active
-        )
-        cap = max(1, min(rem, self.max_chunk))
-        k = 1
-        while k * 2 <= cap:
-            k *= 2
-        smode = max(self.slot_req[i]._smode for i in active)
-        lanes = self._flush_events()
-        if self.paged:
-            toks, self._last_tok, self._cur_len, self.cache = (
-                self._tick_paged(
-                    self.params, self.cache, self._flush_btab(),
-                    self._last_tok, self._cur_len,
-                    lanes, self._spf, self._spi, self._btok, self._bval,
-                    n_steps=k, smode=smode,
+        with _span("chunk"):
+            rem = min(
+                min(
+                    self.slot_req[i].params.max_new - self.slot_req[i].n_generated,
+                    self.max_len - 1 - int(self.slot_len[i]),
                 )
+                for i in active
             )
-        else:
-            toks, self._last_tok, self._cur_len, self.cache = (
-                self._tick(
-                    self.params, self.cache, self._last_tok, self._cur_len,
-                    lanes, self._spf, self._spi, self._btok, self._bval,
-                    n_steps=k, smode=smode,
+            cap = max(1, min(rem, self.max_chunk))
+            k = 1
+            while k * 2 <= cap:
+                k *= 2
+            smode = max(self.slot_req[i]._smode for i in active)
+            lanes = self._flush_events()
+            if self.paged:
+                toks, self._last_tok, self._cur_len, self.cache = (
+                    self._tick_paged(
+                        self.params, self.cache, self._flush_btab(),
+                        self._last_tok, self._cur_len,
+                        lanes, self._spf, self._spi, self._btok, self._bval,
+                        n_steps=k, smode=smode,
+                    )
                 )
-            )
-        stats.ticks += k
-        pending.append(("chunk", toks, [(i, self.slot_req[i]) for i in active], stats))
-        # bookkeeping needs only COUNTS — token values are harvested a
-        # chunk later, overlapping this chunk's device compute
-        for i in active:
-            req = self.slot_req[i]
-            self.slot_len[i] += k
-            req.n_generated += k
-            stats.total_tokens += k
-            if req.n_generated >= req.params.max_new or self.slot_len[i] + 1 >= self.max_len:
-                self._finish(req, i, stats)
+            else:
+                toks, self._last_tok, self._cur_len, self.cache = (
+                    self._tick(
+                        self.params, self.cache, self._last_tok, self._cur_len,
+                        lanes, self._spf, self._spi, self._btok, self._bval,
+                        n_steps=k, smode=smode,
+                    )
+                )
+            stats.ticks += k
+            pending.append(("chunk", toks, [(i, self.slot_req[i]) for i in active], stats))
+            # bookkeeping needs only COUNTS — token values are harvested a
+            # chunk later, overlapping this chunk's device compute
+            for i in active:
+                req = self.slot_req[i]
+                self.slot_len[i] += k
+                req.n_generated += k
+                stats.total_tokens += k
+                if req.n_generated >= req.params.max_new or self.slot_len[i] + 1 >= self.max_len:
+                    self._finish(req, i, stats)
 
     def _spec_depth(self, slot: int) -> int:
         """Adaptive proposal depth for one slot, from its acceptance EWMA.
@@ -1828,118 +1847,119 @@ class ServeEngine:
         sampled token and the exact-match rule, a speculated stream stops
         at exactly the token the sequential engine would have stopped
         at."""
-        self._drain_pending()
-        self._release_stopped(stats)
-        decoding = [
-            i for i, r in enumerate(self.slot_req)
-            if r is not None and self.slot_fed[i] >= len(r.prompt)
-        ]
-        if not decoding:
-            return
-        b = self.B
-        depths = np.zeros(b, np.int32)
-        ctxs: list[Optional[np.ndarray]] = [None] * b
-        for i in decoding:
-            r = self.slot_req[i]
-            rem = min(
-                r.params.max_new - r.n_generated,
-                self.max_len - 1 - int(self.slot_len[i]),
-            )
-            d = min(self.spec_k, rem - 1, self._spec_depth(i)) if r._spec else 0
-            depths[i] = max(d, 0)
-            if depths[i] > 0:
-                ctxs[i] = np.concatenate(
-                    [
-                        np.asarray(r.prompt, np.int64),
-                        np.asarray(r.generated, np.int64),
-                    ]
-                )
-        if depths.any():
-            props = self.drafter.propose(ctxs, depths)
+        with _span("spec"):
+            self._drain_pending()
+            self._release_stopped(stats)
+            decoding = [
+                i for i, r in enumerate(self.slot_req)
+                if r is not None and self.slot_fed[i] >= len(r.prompt)
+            ]
+            if not decoding:
+                return
+            b = self.B
+            depths = np.zeros(b, np.int32)
+            ctxs: list[Optional[np.ndarray]] = [None] * b
             for i in decoding:
-                depths[i] = min(int(depths[i]), len(props[i]))
-        else:
-            props = [[] for _ in range(b)]
-        kmax = max(1, int(depths.max()))
-        depth_k = 1
-        while depth_k < kmax:
-            depth_k *= 2
-        w = depth_k + 1
-        # slot-major verify rows [last_token, draft_1 .. draft_d]; rows
-        # past a slot's depth (and whole inactive slots) carry the
-        # position sentinel — scatter dropped, acceptance depth-masked.
-        # desc and meta share ONE upload (see _spec_fn): pack[:, :b*w] is
-        # the descriptor, pack[:, b*w:] the per-slot (depth, active, cl)
-        pack = np.zeros((3, b * w + b), np.int32)
-        desc = pack[:, : b * w]
-        meta = pack[:, b * w :]
-        desc[2] = self.max_len
-        for i in decoding:
-            r = self.slot_req[i]
-            d = int(depths[i])
-            cl = int(self.slot_len[i])
-            r0 = i * w
-            desc[0, r0] = r.generated[-1]
-            if d:
-                desc[0, r0 + 1 : r0 + 1 + d] = props[i][:d]
-            desc[1, r0 : r0 + 1 + d] = i
-            desc[2, r0 : r0 + 1 + d] = cl + np.arange(d + 1)
-            meta[0, i] = d
-            meta[1, i] = 1
-            meta[2, i] = cl
-        smode = max(self.slot_req[i]._smode for i in decoding)
-        if smode:
-            if not self._sp_fresh:
-                self._put_sp(*self._sp_rows())
-            spf, spi, btok, bval = self._spf, self._spi, self._btok, self._bval
-        else:
-            spf, spi, btok, bval = self._sp0
-        if (depth_k, smode) not in self._spec_shapes:
-            self._spec_shapes.add((depth_k, smode))
-            stats.prefill_compiles += 1
-        if self.paged:
-            targets, commit, self._last_tok, self._cur_len, self.cache = (
-                self._spec_prog_paged(
-                    self.params, self.cache, self._flush_btab(),
-                    self._last_tok, self._cur_len,
-                    self.backend.put_host(pack),
-                    spf, spi, btok, bval, depth_k=depth_k, smode=smode,
+                r = self.slot_req[i]
+                rem = min(
+                    r.params.max_new - r.n_generated,
+                    self.max_len - 1 - int(self.slot_len[i]),
                 )
-            )
-        else:
-            targets, commit, self._last_tok, self._cur_len, self.cache = (
-                self._spec_prog(
-                    self.params, self.cache, self._last_tok, self._cur_len,
-                    self.backend.put_host(pack),
-                    spf, spi, btok, bval, depth_k=depth_k, smode=smode,
+                d = min(self.spec_k, rem - 1, self._spec_depth(i)) if r._spec else 0
+                depths[i] = max(d, 0)
+                if depths[i] > 0:
+                    ctxs[i] = np.concatenate(
+                        [
+                            np.asarray(r.prompt, np.int64),
+                            np.asarray(r.generated, np.int64),
+                        ]
+                    )
+            if depths.any():
+                props = self.drafter.propose(ctxs, depths)
+                for i in decoding:
+                    depths[i] = min(int(depths[i]), len(props[i]))
+            else:
+                props = [[] for _ in range(b)]
+            kmax = max(1, int(depths.max()))
+            depth_k = 1
+            while depth_k < kmax:
+                depth_k *= 2
+            w = depth_k + 1
+            # slot-major verify rows [last_token, draft_1 .. draft_d]; rows
+            # past a slot's depth (and whole inactive slots) carry the
+            # position sentinel — scatter dropped, acceptance depth-masked.
+            # desc and meta share ONE upload (see _spec_fn): pack[:, :b*w] is
+            # the descriptor, pack[:, b*w:] the per-slot (depth, active, cl)
+            pack = np.zeros((3, b * w + b), np.int32)
+            desc = pack[:, : b * w]
+            meta = pack[:, b * w :]
+            desc[2] = self.max_len
+            for i in decoding:
+                r = self.slot_req[i]
+                d = int(depths[i])
+                cl = int(self.slot_len[i])
+                r0 = i * w
+                desc[0, r0] = r.generated[-1]
+                if d:
+                    desc[0, r0 + 1 : r0 + 1 + d] = props[i][:d]
+                desc[1, r0 : r0 + 1 + d] = i
+                desc[2, r0 : r0 + 1 + d] = cl + np.arange(d + 1)
+                meta[0, i] = d
+                meta[1, i] = 1
+                meta[2, i] = cl
+            smode = max(self.slot_req[i]._smode for i in decoding)
+            if smode:
+                if not self._sp_fresh:
+                    self._put_sp(*self._sp_rows())
+                spf, spi, btok, bval = self._spf, self._spi, self._btok, self._bval
+            else:
+                spf, spi, btok, bval = self._sp0
+            if (depth_k, smode) not in self._spec_shapes:
+                self._spec_shapes.add((depth_k, smode))
+                stats.prefill_compiles += 1
+            if self.paged:
+                targets, commit, self._last_tok, self._cur_len, self.cache = (
+                    self._spec_prog_paged(
+                        self.params, self.cache, self._flush_btab(),
+                        self._last_tok, self._cur_len,
+                        self.backend.put_host(pack),
+                        spf, spi, btok, bval, depth_k=depth_k, smode=smode,
+                    )
                 )
-            )
-        stats.ticks += 1
-        stats.spec_ticks += 1
-        # value-blocking by design (see above); ONE transfer for both
-        t_h, c_h = jax.device_get((targets, commit))
-        now = time.perf_counter()
-        for i in decoding:
-            r = self.slot_req[i]
-            c = int(c_h[i])  # accepted run + the bonus token, >= 1
-            d = int(depths[i])
-            stats.spec_proposed += d
-            stats.spec_accepted += c - 1
-            if self.spec.adaptive and d > 0:
-                self._spec_ewma[i] = 0.5 * self._spec_ewma[i] + 0.5 * (
-                    (c - 1) / d
+            else:
+                targets, commit, self._last_tok, self._cur_len, self.cache = (
+                    self._spec_prog(
+                        self.params, self.cache, self._last_tok, self._cur_len,
+                        self.backend.put_host(pack),
+                        spf, spi, btok, bval, depth_k=depth_k, smode=smode,
+                    )
                 )
-            r.n_generated += c
-            self.slot_len[i] += c
-            stats.total_tokens += c
-            for j in range(c):
-                self._credit(r, int(t_h[i, j]), now, stats)
-            self._stamp(r, now)
-            if r.finish_reason is None and (
-                r.n_generated >= r.params.max_new
-                or self.slot_len[i] + 1 >= self.max_len
-            ):
-                self._finish(r, i, stats)
+            stats.ticks += 1
+            stats.spec_ticks += 1
+            # value-blocking by design (see above); ONE transfer for both
+            t_h, c_h = jax.device_get((targets, commit))
+            now = time.perf_counter()
+            for i in decoding:
+                r = self.slot_req[i]
+                c = int(c_h[i])  # accepted run + the bonus token, >= 1
+                d = int(depths[i])
+                stats.spec_proposed += d
+                stats.spec_accepted += c - 1
+                if self.spec.adaptive and d > 0:
+                    self._spec_ewma[i] = 0.5 * self._spec_ewma[i] + 0.5 * (
+                        (c - 1) / d
+                    )
+                r.n_generated += c
+                self.slot_len[i] += c
+                stats.total_tokens += c
+                for j in range(c):
+                    self._credit(r, int(t_h[i, j]), now, stats)
+                self._stamp(r, now)
+                if r.finish_reason is None and (
+                    r.n_generated >= r.params.max_new
+                    or self.slot_len[i] + 1 >= self.max_len
+                ):
+                    self._finish(r, i, stats)
 
     # ------------------------------------------------------------------- run
 
@@ -1953,48 +1973,49 @@ class ServeEngine:
         ``admit=False`` drains in-flight slots without pulling from the
         waiting queue — the controlled-run slice boundary (a cluster about
         to reconfigure wants idle slots, not an empty queue)."""
-        self._apply_cancels(stats)
-        self._release_stopped(stats)
-        if not admit:
-            pass
-        elif self.paged:
-            self._admit_paged(stats)
-        elif self.unified:
-            self._admit_unified(stats, self._pending)
-        else:
-            self._admit(stats)
-        stats.kv_bytes_resident = max(
-            stats.kv_bytes_resident, self.kv_bytes_resident()
-        )
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
-        if not active:
-            self._drain_pending()
+        with _span("step"):
+            self._apply_cancels(stats)
             self._release_stopped(stats)
-            return bool(self.waiting) or any(
-                r is not None for r in self.slot_req
+            if admit:
+                with _span("admit"):
+                    if self.paged:
+                        self._admit_paged(stats)
+                    elif self.unified:
+                        self._admit_unified(stats, self._pending)
+                    else:
+                        self._admit(stats)
+            stats.kv_bytes_resident = max(
+                stats.kv_bytes_resident, self.kv_bytes_resident()
             )
-        if self.unified and self._prefilling:
-            # merge mode: one ragged prefill pack, and — in the same
-            # loop iteration — a fused decode chunk for every decoding
-            # slot (including one whose prompt just completed in this
-            # very pack). Admission never stalls decode.
-            self._packed_tick(stats, self._pending)
-            if self.spec is not None:
+            active = [i for i, r in enumerate(self.slot_req) if r is not None]
+            if not active:
+                self._drain_pending()
+                self._release_stopped(stats)
+                return bool(self.waiting) or any(
+                    r is not None for r in self.slot_req
+                )
+            if self.unified and self._prefilling:
+                # merge mode: one ragged prefill pack, and — in the same
+                # loop iteration — a fused decode chunk for every decoding
+                # slot (including one whose prompt just completed in this
+                # very pack). Admission never stalls decode.
+                self._packed_tick(stats, self._pending)
+                if self.spec is not None:
+                    self._spec_tick(stats)
+                else:
+                    decoding = [
+                        i for i, r in enumerate(self.slot_req)
+                        if r is not None and self.slot_fed[i] >= len(r.prompt)
+                    ]
+                    if decoding:
+                        self._chunk_tick(stats, self._pending, decoding)
+            elif self.spec is not None:
                 self._spec_tick(stats)
             else:
-                decoding = [
-                    i for i, r in enumerate(self.slot_req)
-                    if r is not None and self.slot_fed[i] >= len(r.prompt)
-                ]
-                if decoding:
-                    self._chunk_tick(stats, self._pending, decoding)
-        elif self.spec is not None:
-            self._spec_tick(stats)
-        else:
-            self._chunk_tick(stats, self._pending, active)
-        while len(self._pending) > 1:
-            self._harvest(self._pending.popleft())
-        return True
+                self._chunk_tick(stats, self._pending, active)
+            while len(self._pending) > 1:
+                self._harvest(self._pending.popleft())
+            return True
 
     def kv_bytes_resident(self) -> int:
         """Actual HBM bytes of KV state resident right now, dtype-aware.
