@@ -1,5 +1,6 @@
 """Batched GQA decode-attention kernel: one query token per sequence against
-the full head-major ``[B, KV, S_max, hd]`` cache, per-slot valid lengths.
+one layer of the stacked head-major ``[L, B, KV, S_max, hd]`` cache, per-slot
+valid lengths.
 
 This is the serving-engine hot path: every engine tick runs one of these per
 layer over all batch slots. The grid is (batch, KV head, S tiles); the
@@ -7,13 +8,16 @@ query-head group rides inside the block (a [G, hd] tile — G = H//KV query
 heads share one KV head), so the cache is never expanded ``G``-fold. The
 per-slot length arrives as a scalar-prefetch-style SMEM operand and gates
 whole tiles: tiles entirely past ``cur_len`` are skipped (``pl.when``), so
-short slots in a long cache cost proportionally less.
+short slots in a long cache cost proportionally less. The layer index is a
+second scalar-prefetch operand that only the K/V index maps read: the kernel
+fetches its tiles straight out of the stacked cache, so the layer loop never
+cuts a per-layer copy of the cache for it.
 
 Layout: the cache is head-major so every K/V tile is a 2-D ``(block_s, hd)``
 slab — the block ``(1, 1, block_s, hd)`` keeps the TPU's tiled minor dims
 (sublane, lane) at (positions, head_dim). A position-major ``[B, S, KV, hd]``
 cache would put the KV-head count on the sublane dim, which a one-head block
-cannot tile. Per-position scales of a quantized cache are ``[B, KV, S_max]``
+cannot tile. Per-position scales of a quantized cache are ``[L, B, KV, S_max]``
 rows fetched as whole-KV ``(1, KV, block_s)`` blocks (legal for any KV), and
 the kernel picks its head's row.
 
@@ -107,7 +111,7 @@ def split_refs(refs, quant: bool):
     return (q, k, v, ks, vs, *rest)
 
 
-def _decode_kernel(lens_ref, *refs, quant: bool, **kw):
+def _decode_kernel(lens_ref, layer_ref, *refs, quant: bool, **kw):
     # this slot's cached-token count; the new token sits at index cur
     attend_tile(lens_ref[pl.program_id(0)], *split_refs(refs, quant), **kw)
 
@@ -120,6 +124,7 @@ def decode_attention(
     k: jax.Array,
     v: jax.Array,
     cur_len: jax.Array,
+    layer: jax.Array | None = None,
     *,
     window: int = 0,
     block_s: int = 256,
@@ -127,36 +132,47 @@ def decode_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """q: [B, KV, G, d]; k/v: [B, KV, S_max, d]; cur_len: [B] int32.
+    """q: [B, KV, G, d]; k/v: the stacked [L, B, KV, S_max, d] cache, read
+    at layer ``layer`` (int32 scalar); cur_len: [B] int32. Without
+    ``layer``, k/v are one layer's [B, KV, S_max, d].
 
-    With ``k_scale``/``v_scale`` ([B, KV, S_max] f32) K/V may be int8:
-    tiles dequantize in-register inside the kernel. Returns [B, KV, G, d]
-    attention outputs for the new token."""
+    With ``k_scale``/``v_scale`` ([L, B, KV, S_max] f32, or [B, KV, S_max]
+    without ``layer``) K/V may be int8: tiles dequantize in-register inside
+    the kernel. Returns [B, KV, G, d] attention outputs for the new token."""
+    if layer is None:  # one layer's cache is a stack of one (a free reshape)
+        k, v = k[None], v[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
     b, kvh, g, d = q.shape
-    s_max = k.shape[2]
+    s_max = k.shape[3]
     s_steps = pl.cdiv(s_max, block_s)
     grid = (b, kvh, s_steps)
     quant = k_scale is not None
     kv_spec = pl.BlockSpec(
-        (1, 1, block_s, d), lambda bi, hi, si, lens: (bi, hi, si, 0)
+        (None, 1, 1, block_s, d),
+        lambda bi, hi, si, lens, lyr: (lyr[0], bi, hi, si, 0),
     )
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda bi, hi, si, lens: (bi, hi, 0, 0)),
+        pl.BlockSpec(
+            (1, 1, g, d), lambda bi, hi, si, lens, lyr: (bi, hi, 0, 0)
+        ),
         kv_spec,
     ]
     if quant:
         scale_spec = pl.BlockSpec(
-            (1, kvh, block_s), lambda bi, hi, si, lens: (bi, 0, si)
+            (None, 1, kvh, block_s),
+            lambda bi, hi, si, lens, lyr: (lyr[0], bi, 0, si),
         )
         in_specs += [scale_spec, kv_spec, scale_spec]
     else:
         in_specs.append(kv_spec)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda bi, hi, si, lens: (bi, hi, 0, 0)
+            (1, 1, g, d), lambda bi, hi, si, lens, lyr: (bi, hi, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
@@ -166,8 +182,11 @@ def decode_attention(
     )
 
     cur_len = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (b,))
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     operands = (
-        (cur_len, q, k, k_scale, v, v_scale) if quant else (cur_len, q, k, v)
+        (cur_len, layer, q, k, k_scale, v, v_scale)
+        if quant
+        else (cur_len, layer, q, k, v)
     )
     return pl.pallas_call(
         functools.partial(

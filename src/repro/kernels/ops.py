@@ -303,39 +303,41 @@ def gqa_flash_attention(
 
 
 def decode_attention(
-    q, k, v, cur_len, *, window: int = 0, mode: Mode = "auto",
+    q, k, v, cur_len, layer=None, *, window: int = 0, mode: Mode = "auto",
     block_s: Optional[int] = None, k_scale=None, v_scale=None,
 ):
     """Batched single-token decode attention against the KV cache.
 
     q: [B, H, d] (the new token's query heads); k/v: [B, KV, S_max, d]
-    (head-major decode-cache layout, possibly lower-precision storage);
-    cur_len: [] or [B] tokens already cached per slot. ``k_scale``/``v_scale``
-    ([B, KV, S_max] f32, the cache-resident scale leaves) mark K/V as
-    int8 rows — dequant happens inside the kernel / oracle, never as an
-    f32 cache copy. Returns [B, H, d]."""
+    (head-major decode-cache layout, possibly lower-precision storage), or
+    with ``layer`` (int32 scalar) the stacked ``[L, B, KV, S_max, d]`` cache
+    read at that layer in place; cur_len: [] or [B] tokens already cached
+    per slot. ``k_scale``/``v_scale`` ([B, KV, S_max] f32, stacked like K/V
+    with ``layer``: the cache-resident scale leaves) mark K/V as int8 rows —
+    dequant happens inside the kernel / oracle, never as an f32 cache copy.
+    Returns [B, H, d]."""
     b, h, d = q.shape
-    kvh, s_max = k.shape[1], k.shape[2]
+    kvh, s_max = k.shape[-3], k.shape[-2]
     assert h % kvh == 0, (h, kvh)
     g = h // kvh
     qg = q.reshape(b, kvh, g, d)
     m = _resolve(mode)
     if m == "ref":
         out = ref.decode_attention(
-            qg, k, v, cur_len, window=window,
+            qg, k, v, cur_len, layer, window=window,
             k_scale=k_scale, v_scale=v_scale,
         )
     else:
         if block_s is None:
-            block_s = _blocks(
-                "decode_attention", k.shape, q.dtype, m,
+            block_s = _blocks(  # keyed by one layer's cache shape
+                "decode_attention", k.shape[-4:], q.dtype, m,
                 kv_dtype=None if k_scale is None else k.dtype,
             )["block_s"]
         # no pre-cast of the cache: the kernel upcasts per-tile (int8/bf16
         # storage reads stay at storage width in HBM) and reads the cache
         # and its scales in place
         out = _decode_k.decode_attention(
-            qg, k, v, cur_len,
+            qg, k, v, cur_len, layer,
             window=window, block_s=min(block_s, s_max),
             interpret=(m == "interpret"), k_scale=k_scale, v_scale=v_scale,
         )

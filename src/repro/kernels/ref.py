@@ -115,6 +115,7 @@ def decode_attention(
     k: jax.Array,
     v: jax.Array,
     cur_len: jax.Array,
+    layer: jax.Array | None = None,
     *,
     window: int = 0,
     k_scale: jax.Array | None = None,
@@ -122,13 +123,19 @@ def decode_attention(
 ) -> jax.Array:
     """Single-token grouped decode attention oracle.
 
-    q: [B, KV, G, d]; k/v: [B, KV, S_max, d] (head-major); cur_len: [] or
-    [B] tokens already cached (the new token was scattered at index
-    cur_len, so key position t is valid iff t <= cur_len). Optional
-    ``k_scale``/``v_scale`` ([B, KV, S_max] f32) dequantize int8 K/V rows in-math — garbage scales
-    at invalid positions are as harmless as garbage K/V (masked lanes).
+    q: [B, KV, G, d]; k/v: [B, KV, S_max, d] (head-major), or with ``layer``
+    the stacked [L, B, KV, S_max, d] cache, of which layer ``layer`` is
+    read; cur_len: [] or [B] tokens already cached (the new token was
+    scattered at index cur_len, so key position t is valid iff t <=
+    cur_len). Optional ``k_scale``/``v_scale`` ([B, KV, S_max] f32, stacked
+    like K/V) dequantize int8 K/V rows in-math — garbage scales at invalid
+    positions are as harmless as garbage K/V (masked lanes).
     Returns [B, KV, G, d] in f32 softmax math, cast back to q.dtype.
     """
+    if layer is not None:
+        k, v = k[layer], v[layer]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[layer], v_scale[layer]
     k = dequant_kv(k, k_scale, q.dtype)
     v = dequant_kv(v, v_scale, q.dtype)
     b, kvh, g, d = q.shape

@@ -21,6 +21,7 @@ shard inside a ``shard_map`` (:func:`_per_head`), never on gathered K/V.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -313,11 +314,18 @@ def attention_decode(
     block_tables: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ):
     """One decode step.
 
     x: [B, 1, d]; cache_k/v: [B, KV, S_max, hd]; cur_len: [] or [B] tokens
     already in the cache. Returns (out [B,1,d], new_k, new_v). With
+    ``layer`` (int32 scalar) the cache arguments are instead the whole
+    stacked ``[L, B, KV, S_max, hd]`` cache (scales ``[L, B, KV, S_max]``)
+    that the decode layer loop carries: the new row lands at
+    ``(layer, slot, :, cur_len)`` in place and the attention kernel reads
+    that layer straight out of the stack, so no per-layer slice of the
+    cache is ever cut or written back. With
     ``mesh_info`` the step runs head-sharded over the ``model`` axis
     (merge-mode serving): q and the KV cache split on their head dims, the
     per-shard partial outputs of the ``wo`` contraction all-reduce.
@@ -342,6 +350,7 @@ def attention_decode(
     """
     b, _, d = x.shape
     quant = k_scale is not None
+    kv_axis = 1 if layer is None else 2  # the cache's KV-head axis
 
     with jax.named_scope("attn_in"):
         q = jnp.einsum("bsd,dhk->bshk", x, qweight(params["wq"], x.dtype))
@@ -350,8 +359,8 @@ def attention_decode(
         q = _head_constraint(q, mesh_info, 2)
         k = _head_constraint(k, mesh_info, 2)
         v = _head_constraint(v, mesh_info, 2)
-        cache_k = _head_constraint(cache_k, mesh_info, 1)
-        cache_v = _head_constraint(cache_v, mesh_info, 1)
+        cache_k = _head_constraint(cache_k, mesh_info, kv_axis)
+        cache_v = _head_constraint(cache_v, mesh_info, kv_axis)
         if cfg.qk_norm:
             q = rms_norm(q, params["q_norm"], cfg.norm_eps)
             k = rms_norm(k, params["k_norm"], cfg.norm_eps)
@@ -368,11 +377,13 @@ def attention_decode(
     if block_tables is None:
         with jax.named_scope("kv_write"):
             # scatter the new k/v at cur_len on the head-major position axis
-            cache_k = _scatter_step(cache_k, k.transpose(0, 2, 1, 3), cur_len, 2)
-            cache_v = _scatter_step(cache_v, v.transpose(0, 2, 1, 3), cur_len, 2)
+            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            cache_k = _scatter_step(cache_k, kt, cur_len, 2, layer)
+            cache_v = _scatter_step(cache_v, vt, cur_len, 2, layer)
             if quant:
-                k_scale = _scatter_step(k_scale, ks.transpose(0, 2, 1), cur_len, 2)
-                v_scale = _scatter_step(v_scale, vs.transpose(0, 2, 1), cur_len, 2)
+                kst, vst = ks.transpose(0, 2, 1), vs.transpose(0, 2, 1)
+                k_scale = _scatter_step(k_scale, kst, cur_len, 2, layer)
+                v_scale = _scatter_step(v_scale, vst, cur_len, 2, layer)
 
         # grouped decode attention: never expands the cache to H heads
         # (materializing [B,S,H,hd] per layer is a groups× transient blowup
@@ -383,7 +394,8 @@ def attention_decode(
                 mesh_info,
                 functools.partial(ops.decode_attention, window=cfg.sliding_window),
                 dict(q=q[:, 0], k=cache_k, v=cache_v, k_scale=k_scale, v_scale=v_scale),
-                dict(cur_len=cur_len),
+                dict(cur_len=cur_len, layer=layer),
+                kv_axis=kv_axis,
             )[:, None]  # [B,1,H,hd]
     else:
         # paged: (slot, cur_len) -> (block, offset) through the sequence's
@@ -557,7 +569,8 @@ def attention_packed(
 
 
 def _scatter_step(
-    cache: jax.Array, new: jax.Array, cur_len: jax.Array, axis: int = 1
+    cache: jax.Array, new: jax.Array, cur_len: jax.Array, axis: int = 1,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Write ``new`` (size 1 on ``axis``) into ``cache`` at position
     cur_len (per-batch) of its position axis ``axis`` — 1 for a
@@ -568,7 +581,37 @@ def _scatter_step(
     uses one dynamic_update_slice; per-batch lengths use a vmapped
     dynamic_update_slice — an O(1)-per-row write instead of the old O(S)
     one-hot blend that read+wrote the entire cache every decode step.
+
+    With ``layer`` (int32 scalar) ``cache`` is the stacked ``[L, ...]``
+    cache (``axis`` still counts from the per-layer ``B`` axis) and each
+    slot's row is written at ``(layer, slot, ..., cur_len)`` in place,
+    O(B) rows a call. A lone row at an unknown offset of the position
+    axis is narrower than a memory tile of the TPU, and to write one the
+    compiler relayouts the whole stacked cache around every attention
+    call; so each slot's write reads the tile-aligned window of ``w``
+    positions that holds its row, patches the row and writes the window
+    back (``w`` a multiple of every tile height, 128 where positions are
+    the minor axis). Positions clamp into range as a
+    dynamic_update_slice's start does.
     """
+    if layer is not None:
+        b, s = cache.shape[1], cache.shape[axis + 1]
+        w = math.gcd(s, 128 if axis + 2 == cache.ndim else 32)
+        pos = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (b,))
+        pos = jnp.clip(pos, 0, s - 1)
+        win = (1, 1) + cache.shape[2:axis + 1] + (w,) + cache.shape[axis + 2:]
+        rows = jax.lax.broadcasted_iota(jnp.int32, win, axis + 1)
+
+        def write_slot(i, c):
+            p = pos[i]
+            start = [layer, i] + [0] * (c.ndim - 2)
+            start[axis + 1] = jnp.bitwise_and(p, -w)  # known tile-aligned
+            old = jax.lax.dynamic_slice(c, start, win)
+            row = jax.lax.dynamic_index_in_dim(new, i, keepdims=False)
+            upd = jnp.where(rows == p - start[axis + 1], row.astype(c.dtype), old)
+            return jax.lax.dynamic_update_slice(c, upd, start)
+
+        return jax.lax.fori_loop(0, b, write_slot, cache)
     cur_len = jnp.asarray(cur_len)
     if cur_len.ndim == 0:
         start = [0] * cache.ndim
@@ -585,32 +628,37 @@ def _scatter_step(
     return jax.vmap(write_row)(cache, new, pos)
 
 
-def _per_head(mesh_info, fn, sharded: dict, replicated: dict) -> jax.Array:
+def _per_head(
+    mesh_info, fn, sharded: dict, replicated: dict, kv_axis: int = 1
+) -> jax.Array:
     """Call attention kernel ``fn(**sharded, **replicated)``, per KV-head
     shard under merge-mode tensor parallelism.
 
     A Pallas call cannot be partitioned by GSPMD: left alone it would be
     fed all-gathered K/V. When the KV heads divide the ``model`` axis — the
     placement ``serve_cache_shardings`` gives the cache — ``fn`` runs inside
-    a ``shard_map`` where every ``sharded`` operand (queries ``[., H, d]``,
-    head-major caches ``[., KV, S, d]``, scales ``[., KV, S]``) splits on
-    axis 1 and every ``replicated`` one (descriptors, masks, block tables)
-    is whole: each device attends its own heads against its own cache
-    shard, and the output ``[., H, d]`` stays head-sharded. ``None``
-    operands are dropped. Off-mesh (or with indivisible heads) it is a
-    plain call."""
+    a ``shard_map`` where the queries ``q`` (``[., H, d]``) split on axis 1,
+    every other ``sharded`` operand (head-major caches ``[., KV, S, d]``,
+    scales ``[., KV, S]``) on its KV-head axis ``kv_axis`` (2 for the
+    stacked ``[L, B, KV, ...]`` decode cache), and every ``replicated`` one
+    (descriptors, masks, block tables, the layer index) is whole: each
+    device attends its own heads against its own cache shard, and the
+    output ``[., H, d]`` stays head-sharded. ``None`` operands are dropped.
+    Off-mesh (or with indivisible heads) it is a plain call."""
     sharded = {k: a for k, a in sharded.items() if a is not None}
     replicated = {k: a for k, a in replicated.items() if a is not None}
     ms = 1 if mesh_info is None else mesh_info.model_size
-    if ms <= 1 or sharded["k"].shape[1] % ms:
+    if ms <= 1 or sharded["k"].shape[kv_axis] % ms:
         return fn(**sharded, **replicated)
     from jax.sharding import PartitionSpec as P
 
     heads = P(None, "model")
+    cache_heads = P(*[None] * kv_axis, "model")
+    specs = {k: heads if k == "q" else cache_heads for k in sharded}
     return jax.shard_map(
         lambda sh, rep: fn(**sh, **rep),
         mesh=mesh_info.mesh,
-        in_specs=(heads, P()),
+        in_specs=(specs, P()),
         out_specs=heads,
         check_vma=False,
     )(sharded, replicated)

@@ -183,14 +183,19 @@ def mla_decode(
     cache_ckv: jax.Array,
     cache_krope: jax.Array,
     cur_len: jax.Array,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step with the latent cache.
 
-    x: [B,1,d]; cache_ckv: [B,S,kv_lora]; cache_krope: [B,S,rope_dim].
+    x: [B,1,d]; cache_ckv: [B,S,kv_lora]; cache_krope: [B,S,rope_dim]. With
+    ``layer`` (int32 scalar) the caches are the whole stacked ``[L,B,S,.]``
+    latents the decode layer loop carries: the new rows land at
+    ``(layer, slot, cur_len)`` in place, and attention reads that layer out
+    of the updated stack (a read-only slice XLA fuses into its einsums).
     """
     m = cfg.mla
     b = x.shape[0]
-    s_max = cache_ckv.shape[1]
+    s_max = cache_ckv.shape[-2]
     with jax.named_scope("attn_in"):
         pos = jnp.broadcast_to(jnp.asarray(cur_len), (b,))[:, None]  # [B,1]
         q = _project_q(params, cfg, x)  # [B,1,H,nope+rope]
@@ -206,17 +211,21 @@ def mla_decode(
         q_eff = jnp.einsum("bshk,rhk->bshr", q_nope, w_uk)
 
     with jax.named_scope("kv_write"):
-        cache_ckv = _scatter_step(cache_ckv, c_kv, cur_len)
-        cache_krope = _scatter_step(cache_krope, k_rope, cur_len)
+        cache_ckv = _scatter_step(cache_ckv, c_kv, cur_len, 1, layer)
+        cache_krope = _scatter_step(cache_krope, k_rope, cur_len, 1, layer)
 
     # the latent MQA core: q_eff·cache scores through the latent readout
     with jax.named_scope("attention"):
+        ckv_l, krope_l = cache_ckv, cache_krope
+        if layer is not None:
+            ckv_l = jax.lax.dynamic_index_in_dim(cache_ckv, layer, keepdims=False)
+            krope_l = jax.lax.dynamic_index_in_dim(cache_krope, layer, keepdims=False)
         scores = (
             jnp.einsum("bshr,btr->bhst", q_eff.astype(jnp.float32),
-                       cache_ckv.astype(jnp.float32))
+                       ckv_l.astype(jnp.float32))
             + jnp.einsum(
                 "bshk,btk->bhst", q_rope.astype(jnp.float32),
-                cache_krope.astype(jnp.float32)
+                krope_l.astype(jnp.float32)
             )
         ) * ((m.nope_head_dim + m.rope_head_dim) ** -0.5)
         valid = (jnp.arange(s_max)[None, :]
@@ -224,7 +233,7 @@ def mla_decode(
         scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
         lat = jnp.einsum(
-            "bhst,btr->bshr", probs.astype(x.dtype), cache_ckv.astype(x.dtype)
+            "bhst,btr->bshr", probs.astype(x.dtype), ckv_l.astype(x.dtype)
         )
 
     # decompress through W_uv (absorbed output)

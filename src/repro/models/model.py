@@ -582,14 +582,18 @@ class LM:
     def _block_decode(
         self, blk: Params, x: jax.Array, cache_l: Params, cur_len: jax.Array,
         block_tables: Optional[jax.Array] = None,
+        layer: Optional[jax.Array] = None,
     ) -> tuple[jax.Array, Params]:
-        """One layer's decode. cache_l leaves have NO leading L axis here."""
+        """One layer's decode. cache_l leaves have NO leading L axis here,
+        unless ``layer`` is given: then they are the whole stacked cache,
+        written and read at that layer in place."""
         cfg = self.cfg
         with jax.named_scope("attn_in"):
             h = rms_norm(x, blk["norm1"], cfg.norm_eps)
         if cfg.mla is not None:
             a, ckv, krope = mla_mod.mla_decode(
-                blk["attn"], cfg, h, cache_l["ckv"], cache_l["krope"], cur_len
+                blk["attn"], cfg, h, cache_l["ckv"], cache_l["krope"], cur_len,
+                layer=layer,
             )
             new_cache = {"ckv": ckv, "krope": krope}
         elif "k_scale" in cache_l:  # quantized-row cache: scales ride along
@@ -597,12 +601,14 @@ class LM:
                 blk["attn"], cfg, h, cache_l["k"], cache_l["v"], cur_len,
                 mesh_info=self.mesh_info, block_tables=block_tables,
                 k_scale=cache_l["k_scale"], v_scale=cache_l["v_scale"],
+                layer=layer,
             )
             new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
         else:
             a, ck, cv = attn_mod.attention_decode(
                 blk["attn"], cfg, h, cache_l["k"], cache_l["v"], cur_len,
                 mesh_info=self.mesh_info, block_tables=block_tables,
+                layer=layer,
             )
             new_cache = {"k": ck, "v": cv}
         with jax.named_scope("attn_out"):
@@ -858,6 +864,11 @@ class LM:
         (tokens already cached). Returns (logits [B,1,V], new_cache).
         With ``block_tables``, ``cache`` is a paged pool (see
         :meth:`packed_step`) — dense/moe positional-KV families only.
+
+        Dense/moe layers over the slot cache carry the whole stacked cache
+        through their layer loop: each layer writes its new row per slot in
+        place and reads its layer out of the stack, so a step moves
+        O(B * L) rows of the cache, never the whole of it.
         """
         cfg = self.cfg
         if block_tables is not None and not self.has_positional_kv:
@@ -867,7 +878,29 @@ class LM:
         else:
             x = embed_tokens(params["embed"], batch["tokens"])
 
-        if cfg.family in ("dense", "moe"):
+        if cfg.family in ("dense", "moe") and block_tables is None:
+            # the stacked cache is the layer loop's carry: each layer writes
+            # its new rows into it in place and reads its layer out of it
+            def carried(x, cache, blocks, first):
+                def body(carry, xs):
+                    blk, layer = xs
+                    return self._block_decode(
+                        blk, *carry, cur_len, layer=layer
+                    ), None
+
+                n = jax.tree.leaves(blocks)[0].shape[0]
+                layers = first + jnp.arange(n, dtype=jnp.int32)
+                return jax.lax.scan(body, (x, cache), (blocks, layers))[0]
+
+            if cfg.family == "moe" and cfg.first_k_dense:
+                x, cache = carried(x, cache, params["dense_blocks"], 0)
+                x, new_cache = carried(
+                    x, cache, params["moe_blocks"], cfg.first_k_dense
+                )
+            else:
+                blocks = params["blocks"] if cfg.family == "dense" else params["moe_blocks"]
+                x, new_cache = carried(x, cache, blocks, 0)
+        elif cfg.family in ("dense", "moe"):  # paged pool: per-layer xs scan
             if cfg.family == "moe" and cfg.first_k_dense:
                 kd = cfg.first_k_dense
                 dense_cache = jax.tree.map(lambda c: c[:kd], cache)

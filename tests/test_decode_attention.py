@@ -79,6 +79,40 @@ def test_decode_kernel_low_precision_cache(rng):
     )
 
 
+@pytest.mark.parametrize("layer", [0, 2, 4])  # first, middle, last of L=5
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_decode_kernel_reads_stacked_cache_at_layer(rng, kv_dtype, layer):
+    """The kernel on the stacked [L, B, KV, S, hd] cache at layer ``l``
+    equals the oracle on that layer's own k[l], v[l] (and scales)."""
+    n_layers, b, h, kv, hd, s_max = 5, 3, 6, 2, 16, 40
+    cur = jnp.asarray([0, 9, 39], jnp.int32)
+    q = _rand(rng, (b, h, hd))
+    shape = (n_layers, b, kv, s_max, hd)
+    if kv_dtype == "int8":
+        k = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.005, 0.02, shape[:-1]), jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.005, 0.02, shape[:-1]), jnp.float32)
+        one = dict(k_scale=ks[layer], v_scale=vs[layer])
+    else:
+        k = _rand(rng, shape).astype(jnp.bfloat16)
+        v = _rand(rng, shape).astype(jnp.bfloat16)
+        ks = vs = None
+        one = {}
+    lyr = jnp.int32(layer)
+    got = ops.decode_attention(
+        q, k, v, cur, lyr, mode="interpret", block_s=16, k_scale=ks, v_scale=vs
+    )
+    expect = ops.decode_attention(q, k[layer], v[layer], cur, mode="ref", **one)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(expect), rtol=2e-4, atol=2e-4
+    )
+    got_ref = ops.decode_attention(
+        q, k, v, cur, lyr, mode="ref", k_scale=ks, v_scale=vs
+    )
+    np.testing.assert_array_equal(np.asarray(got_ref), np.asarray(expect))
+
+
 # ---------------------------------------------------------------------------
 # Layer-level parity: attention_decode vs an independently-built oracle,
 # with qk-norm and sliding window enabled

@@ -244,3 +244,63 @@ def test_greedy_spec_verify_program_is_threefry_and_sort_free():
         jax.make_jaxpr(partial(eng._spec_fn, depth_k=2, smode=1))(*args).jaxpr
     )
     assert any(_prng(n) for n in sampled), sorted(sampled)
+
+
+def _scan_eqns(jaxpr):
+    """Every ``scan`` equation of the program, in sub-jaxprs too."""
+    stack, found = [jaxpr], []
+    while stack:
+        j = stack.pop()
+        found += [e for e in j.eqns if e.primitive.name == "scan"]
+        stack.extend(jax.core.subjaxprs(j))
+    return found
+
+
+@pytest.mark.parametrize(
+    "name,kv_dtype",
+    [
+        ("codeqwen1.5-7b", None),  # GQA
+        ("codeqwen1.5-7b", "int8"),  # GQA, quantized rows + scales
+        ("minicpm3-4b", None),  # MLA latents
+        ("deepseek-v2-lite-16b", None),  # MoE + MLA + first_k_dense
+    ],
+)
+def test_decode_step_carries_the_stacked_cache(monkeypatch, name, kv_dtype):
+    """No scan of a dense-cache decode step takes a cache leaf (or a layer
+    slice of one) in its ``xs`` or returns one in its ``ys``: the layer
+    loop carries the stacked cache and writes its rows in place. The GQA
+    attention call receives the whole stacked leaf and a layer index."""
+    from repro.configs import get_arch
+    from repro.models import LM
+
+    cfg = get_arch(name).reduced()
+    m = LM(cfg)
+    params = m.init(jax.random.key(0))
+    cache = m.init_cache(2, 24, kv_dtype=kv_dtype)
+    per_layer = {tuple(c.shape[1:]) for c in jax.tree.leaves(cache)}
+    seen = []
+
+    def spy(q, k, v, cur_len, layer=None, **kw):
+        seen.append((k.shape, layer is not None))
+        return real(q, k, v, cur_len, layer, **kw)
+
+    real = ops.decode_attention
+    monkeypatch.setattr(ops, "decode_attention", spy)
+    jaxpr = jax.make_jaxpr(m.decode_step)(
+        params, cache, {"tokens": jnp.zeros((2, 1), jnp.int32)},
+        jnp.zeros((2,), jnp.int32),
+    ).jaxpr
+    scans = _scan_eqns(jaxpr)
+    assert scans
+    for eqn in scans:
+        n_in = eqn.params["num_consts"] + eqn.params["num_carry"]
+        xs, ys = eqn.invars[n_in:], eqn.outvars[eqn.params["num_carry"]:]
+        for var in (*xs, *ys):
+            assert tuple(var.aval.shape[1:]) not in per_layer, var.aval
+    assert not any(
+        e.primitive.name == "concatenate" for e in jaxpr.eqns
+    ), "the stacked cache is split and joined again"
+    if cfg.mla is None:
+        assert seen and all(
+            s == cache["k"].shape and has_layer for s, has_layer in seen
+        ), seen

@@ -11,6 +11,7 @@ Shapes: codeqwen1.5-7b in bf16 (H=32, KV=4, hd=128) on a 16-slot,
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -43,9 +44,9 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, donate=()):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    hlo = jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
     return hlo
 
@@ -77,6 +78,62 @@ def test_decode_attention_compiles(one_chip, kv_dtype):
         _call(ops.decode_attention, quant), one_chip,
         ((B, H, HD), BF16), *_kv(jnp.dtype(kv_dtype), quant, B, S_MAX), ((B,), I32),
     )
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_stacked_decode_attention_compiles(one_chip, kv_dtype):
+    # the decode layer loop's carried [L, B, KV, S, hd] cache, read at a
+    # traced layer index (L = 16, the codeqwen cut the benchmark serves)
+    quant = kv_dtype == "int8"
+    k, *rest = _kv(jnp.dtype(kv_dtype), quant, B, S_MAX)
+    stack = lambda spec: ((16, *spec[0]), spec[1])  # noqa: E731
+    layered = _call(ops.decode_attention, quant)
+
+    def fn(q, *args):
+        *cache, cur, layer = args
+        return layered(q, *cache, cur, layer)
+
+    _compile(
+        fn, one_chip,
+        ((B, H, HD), BF16), *map(stack, (k, *rest)), ((B,), I32), ((), I32),
+    )
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_carried_cache_layer_loop_copies_no_cache(one_chip, kv_dtype):
+    """The decode layer loop as the model runs it: the stacked cache is the
+    carry, each layer writes one row per slot (``_scatter_step`` at a layer)
+    and the kernel reads that layer in place. The compiled loop holds no
+    copy of a stacked leaf: a write the chip's tiles cannot take in place
+    makes the compiler relayout the whole cache around every kernel call."""
+    from repro.models.attention import _scatter_step
+
+    quant = kv_dtype == "int8"
+    n_layers = 4
+    leaves = [((n_layers, *s), d) for s, d in _kv(jnp.dtype(kv_dtype), quant, B, S_MAX)]
+
+    def loop(q, new, cur, *cache):
+        def body(cache, layer):
+            rows = [new if c.ndim == 5 else new[..., 0] for c in cache]
+            cache = [_scatter_step(c, r, cur, 2, layer) for c, r in zip(cache, rows)]
+            k, ks, v, vs = cache if quant else (cache[0], None, cache[1], None)
+            o = ops.decode_attention(
+                q, k, v, cur, layer, mode="pallas", k_scale=ks, v_scale=vs
+            )
+            return cache, o
+
+        return jax.lax.scan(body, list(cache), jnp.arange(n_layers, dtype=I32))
+
+    hlo = _compile(
+        loop, one_chip, ((B, H, HD), BF16), ((B, KV, 1, HD), F32), ((B,), I32),
+        *leaves, donate=tuple(range(3, 3 + len(leaves))),
+    )
+    stacked = {s for s, _ in leaves}
+    copies = [
+        tuple(int(d) for d in m.split(","))
+        for m in re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", hlo)
+    ]
+    assert not stacked & set(copies), copies
 
 
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
