@@ -20,6 +20,7 @@ gap, not the program's, against the limit.
 from __future__ import annotations
 
 import importlib
+import inspect
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,23 @@ def _pad(n: int) -> int:
     return -(-n // QUERY_BLOCK) * QUERY_BLOCK
 
 
+def layer_weights(fam, hf: dict, seed: int, l: int, dtype: str) -> dict:
+    """Layer ``l``'s weights for the reference family ``fam``, regenerated
+    from the seed as served and upcast to f32. A family whose
+    ``layer_leaves(hf, l)`` takes the layer maps each key to ``(path,
+    stack index, shape, dtype)``, a dtype of None being the deployment's:
+    its layers may differ in kind and sit in several stacks. The
+    one-argument ``layer_leaves(hf)`` maps each key to ``(path, shape)``,
+    the same for every layer, at stack index ``l`` in the deployment's
+    dtype."""
+    if len(inspect.signature(fam.layer_leaves).parameters) == 2:
+        leaves = fam.layer_leaves(hf, l)
+    else:
+        leaves = {k: (path, l, shape, None) for k, (path, shape) in fam.layer_leaves(hf).items()}
+    return {k: weights.layer_leaf(seed, path, i, shape, leaf_dtype or dtype)
+            for k, (path, i, shape, leaf_dtype) in leaves.items()}
+
+
 def gaps(conf: dict, seed: int, sample: list, control: bool = False) -> dict:
     """Run the reference over ``sample`` [(prompt, served)]; return the
     widest served-token gap and, with ``control``, the float8 control's."""
@@ -70,10 +88,8 @@ def gaps(conf: dict, seed: int, sample: list, control: bool = False) -> dict:
             toks = np.concatenate([prompt, served[:-1]]).astype(np.int32)
             seqs.append(np.pad(toks, (0, _pad(len(toks)) - len(toks))))
         xs = {p: [fam.embed(plain, jnp.asarray(s), hf) for s in seqs] for p in precs}
-        leaves = fam.layer_leaves(hf)
         for l in range(hf["num_hidden_layers"]):
-            w = {k: weights.layer_leaf(seed, path, l, shape, dtype)
-                 for k, (path, shape) in leaves.items()}
+            w = layer_weights(fam, hf, seed, l, dtype)
             for p in precs:
                 xs[p] = [layer[p](x, w) for x in xs[p]]
             del w

@@ -147,7 +147,7 @@ _ARCH_KEYS = {
     "num_hidden_layers": lambda c: c.n_layers,
     "num_attention_heads": lambda c: c.n_heads,
     "num_key_value_heads": lambda c: c.n_kv_heads,
-    "intermediate_size": lambda c: c.d_ff,
+    "intermediate_size": lambda c: c.dense_ff or c.d_ff,  # the dense MLP's width
     "vocab_size": lambda c: c.vocab_size,
     "tie_word_embeddings": lambda c: c.tie_embeddings,
     "rope_theta": lambda c: c.rope_theta,
@@ -157,14 +157,27 @@ _ARCH_KEYS = {
     "qk_nope_head_dim": lambda c: c.mla.nope_head_dim,
     "qk_rope_head_dim": lambda c: c.mla.rope_head_dim,
     "v_head_dim": lambda c: c.mla.v_head_dim,
+    "moe_intermediate_size": lambda c: c.moe.expert_ff or c.d_ff,
+    "num_experts_per_tok": lambda c: c.moe.top_k,
+    "n_shared_experts": lambda c: c.moe.n_shared,
+    "first_k_dense_replace": lambda c: c.first_k_dense,
 }
 
 
 def as_run(conf: dict) -> dict:
     """The configuration as the program runs it: the published keys, with
     the program's departures (``runs_as``, each explained under
-    ``assumed``) in their place."""
-    return {**conf["config"], **conf.get("runs_as", {})}
+    ``assumed``) in their place. An expert model's ``n_routed_experts``
+    counts the experts this chip holds; ``deployment.experts``
+    (``published``, ``shards``, ``first``) states the published count and
+    the first expert held, handed on as ``n_routed_experts_published`` and
+    ``first_routed_expert`` (all of them and 0 where one chip holds all)."""
+    hf = {**conf["config"], **conf.get("runs_as", {})}
+    if "n_routed_experts" in hf:
+        experts = conf.get("deployment", {}).get("experts", {})
+        hf["n_routed_experts_published"] = experts.get("published", hf["n_routed_experts"])
+        hf["first_routed_expert"] = experts.get("first", 0)
+    return hf
 
 
 def arch_config(conf: dict):
@@ -178,8 +191,28 @@ def arch_config(conf: dict):
         dtype=conf["deployment"]["dtype"],
     )
     for key, get in _ARCH_KEYS.items():
-        if key in hf and get(cfg) != hf[key]:
+        if key not in hf:
+            continue
+        # a published null query LoRA is the program's 0: a dense projection
+        want = 0 if hf[key] is None and key == "q_lora_rank" else hf[key]
+        if get(cfg) != want:
             raise ValueError(f"{conf['name']}: program has {key}={get(cfg)}, file {hf[key]}")
+    if "n_routed_experts" in hf:
+        held, published = hf["n_routed_experts"], hf["n_routed_experts_published"]
+        experts = conf["deployment"].get("experts")
+        if experts and (published != held * experts["shards"] or hf["first_routed_expert"] % held
+                        or not 0 <= hf["first_routed_expert"] < published):
+            raise ValueError(f"{conf['name']}: deployment.experts {experts} does not cut "
+                             f"{published} experts into blocks of {held}")
+        from repro.models import LM
+
+        # experts held and routed over, as the program's parameter shapes
+        # state them: the expert stack's expert axis, the router's width
+        moe = LM(cfg).param_specs()["moe_blocks"]["moe"]
+        got = (moe["w_in"].shape[1], moe["router"].shape[-1])
+        if got != (held, published):
+            raise ValueError(f"{conf['name']}: program holds {got[0]} of {got[1]} experts, "
+                             f"file {held} of {published}")
     return cfg
 
 

@@ -8,8 +8,14 @@ integer arithmetic up to one scaling multiply, so the plain reference can
 regenerate any single layer (``layer_leaf``) bit for bit without holding
 the whole model and without taking anything the program made.
 
+A leaf is stacked where its top-level subtree's name ends in ``blocks``
+(``blocks``, and an expert model's ``dense_blocks`` and ``moe_blocks``);
+its block ``l`` is the subtree's layer ``l``.
+
 Values are uniform with the standard deviation of a ``1/sqrt(fan_in)``
-initialisation; the token embedding has std 0.02 (the usual initializer
+initialisation, the fan-in of an expert stack ``[E, fan_in, ...]`` (a
+``moe`` subtree's ``w_in``, ``w_gate``, ``w_out``) taken after its expert
+axis; the token embedding has std 0.02 (the usual initializer
 range), so that a tied output head does not make every position predict
 its own input token. Norm scales are drawn around 1 so that a path which
 ignores them cannot agree with the reference.
@@ -25,7 +31,8 @@ import jax
 import jax.numpy as jnp
 
 ROWS_PER_BLOCK = 1024
-STACKED = ("blocks",)  # subtrees whose leaves carry a leading layer axis
+STACKED_SUFFIX = "blocks"  # top-level subtrees whose leaves carry a layer axis
+EXPERT_LEAVES = ("w_in", "w_gate", "w_out")  # under ``moe``: [E, fan_in, ...]
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -42,8 +49,12 @@ def _uniform(key, shape) -> jax.Array:
     return (u + 0.5) * (2.0**-22) - 1.0
 
 
-def _scale(name: str, block_shape: tuple[int, ...]) -> tuple[float, float]:
-    """(offset, half-width) of the uniform draw for a leaf named ``name``."""
+def _scale(path: str, block_shape: tuple[int, ...]) -> tuple[float, float]:
+    """(offset, half-width) of the uniform draw for the leaf at ``path``."""
+    parts = path.split("/")
+    name = parts[-1]
+    if parts[-2:-1] == ["moe"] and name in EXPERT_LEAVES:
+        block_shape = block_shape[1:]  # one expert's matrix
     if "norm" in name:
         return 1.0, 0.25
     if name == "tok":
@@ -55,10 +66,11 @@ def _scale(name: str, block_shape: tuple[int, ...]) -> tuple[float, float]:
     return 0.0, std * math.sqrt(3.0)
 
 
-def _block(key, name, block_shape, dtype, scale_shape=None) -> jax.Array:
-    """One block of a leaf; ``scale_shape`` is the shape whose fan-in sets
-    the scale (the block's own for a layer, the whole leaf for rows)."""
-    off, half = _scale(name, scale_shape or block_shape)
+def _block(key, path, block_shape, dtype, scale_shape=None) -> jax.Array:
+    """One block of the leaf at ``path``; ``scale_shape`` is the shape whose
+    fan-in sets the scale (the block's own for a layer, the whole leaf for
+    rows)."""
+    off, half = _scale(path, scale_shape or block_shape)
     x = _uniform(key, block_shape) * half
     if off:
         x = x + off
@@ -73,21 +85,25 @@ def _leaf_key(skey, path: str) -> jax.Array:
     return jax.random.fold_in(skey, zlib.crc32(path.encode()))
 
 
+def stacked(path: str) -> bool:
+    """Whether the leaf at ``path`` carries a leading layer axis."""
+    return path.split("/", 1)[0].endswith(STACKED_SUFFIX)
+
+
 def _make_leaf(skey, path: str, shape, dtype) -> jax.Array:
-    name = path.rsplit("/", 1)[-1]
     key = _leaf_key(skey, path)
-    if path.split("/", 1)[0] in STACKED:
+    if stacked(path):
         block_shape = tuple(shape[1:])
         return jax.lax.map(
-            lambda i: _block(jax.random.fold_in(key, i), name, block_shape, dtype),
+            lambda i: _block(jax.random.fold_in(key, i), path, block_shape, dtype),
             jnp.arange(shape[0]),
         )
     if len(shape) < 2:
-        return _block(jax.random.fold_in(key, 0), name, tuple(shape), dtype)
+        return _block(jax.random.fold_in(key, 0), path, tuple(shape), dtype)
     n = -(-shape[0] // ROWS_PER_BLOCK)
     block_shape = (ROWS_PER_BLOCK, *shape[1:])
     rows = jax.lax.map(
-        lambda i: _block(jax.random.fold_in(key, i), name, block_shape, dtype,
+        lambda i: _block(jax.random.fold_in(key, i), path, block_shape, dtype,
                          scale_shape=tuple(shape)),
         jnp.arange(n),
     )
@@ -110,7 +126,7 @@ def build(param_specs, seed: int, out_shardings=None):
 @functools.partial(jax.jit, static_argnames=("path", "shape", "dtype"))
 def _layer(skey, i, *, path, shape, dtype):
     key = jax.random.fold_in(_leaf_key(skey, path), i)
-    return _block(key, path.rsplit("/", 1)[-1], shape, dtype).astype(jnp.float32)
+    return _block(key, path, shape, dtype).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("path", "shape", "dtype"))
@@ -120,8 +136,9 @@ def _plain(skey, *, path, shape, dtype):
 
 def layer_leaf(seed: int, path: str, layer: int, shape, dtype="bfloat16"):
     """Layer ``layer`` of the stacked leaf at ``path`` (e.g.
-    ``"blocks/attn/wq"``) as served, upcast to f32; ``shape`` is one
-    layer's shape. Bit-identical to ``build(...)[path][layer]``."""
+    ``"blocks/attn/wq"``, ``"moe_blocks/moe/router"``) as served in the
+    leaf's own ``dtype``, upcast to f32; ``shape`` is one layer's shape.
+    Bit-identical to ``build(...)[path][layer]``."""
     return _layer(seed_key(seed), jnp.int32(layer), path=path,
                   shape=tuple(shape), dtype=jnp.dtype(dtype))
 
